@@ -23,11 +23,9 @@ os.environ.setdefault("XLA_FLAGS", "")
 
 import numpy as np
 
-import jax
+from lodestar_tpu.utils import compile_cache  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/lodestar_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+compile_cache.configure()
 
 from lodestar_tpu import params
 from lodestar_tpu import types as T

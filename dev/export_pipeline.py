@@ -41,7 +41,6 @@ PLATFORM = sys.argv[1] if len(sys.argv) > 1 else "tpu"
 def capture_bench_dispatches():
     """Build the bench world and record every device dispatch the
     verifier would make for its job shapes."""
-    os.environ.setdefault("BENCH_PLATFORM", "cpu")
     from lodestar_tpu.bls.pubkey_table import PubkeyTable
     from lodestar_tpu.bls.signature_set import WireSignatureSet
     from lodestar_tpu.bls.verifier import TpuBlsVerifier
@@ -74,7 +73,6 @@ def capture_bench_dispatches():
     # jit_hash_to_g2_device on this host) and the capture needs VALUES,
     # not device performance
     verifier.messages.use_device = False
-    verifier._use_export = False  # dispatches are captured, not exported
     captured = []
 
     def fake_call(name, fn, args):

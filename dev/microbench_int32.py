@@ -28,11 +28,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/lodestar_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from lodestar_tpu.utils import compile_cache  # noqa: E402
+
+compile_cache.configure()
 
 from lodestar_tpu.crypto import fields as GT
 from lodestar_tpu.kernels import core as C

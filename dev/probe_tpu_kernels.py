@@ -18,11 +18,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/lodestar_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from lodestar_tpu.utils import compile_cache  # noqa: E402
+
+compile_cache.configure()
 
 from lodestar_tpu.crypto import bls as GTB
 from lodestar_tpu.crypto.hash_to_curve import hash_to_g2
@@ -150,7 +151,7 @@ def main():
                 timed(
                     "final",
                     jax.jit(lambda ai, *f: KV._tiled(
-                        KV._k_final_one, (ai,) + f,
+                        KV._k_final, (ai,) + f,
                         [1] + [KV.NL] * 24, [1], KV.BT)),
                     jnp.zeros((1, KV.BT), jnp.int32), *(list(fp_) + list(fN)),
                 )

@@ -54,7 +54,8 @@ def _git_changed_files() -> Optional[Set[str]]:
     if top is None:
         return None
     cmds = [
-        ["git", "diff", "--name-only", "HEAD", "--"],
+        # deleted files have nothing left to lint
+        ["git", "diff", "--name-only", "--diff-filter=d", "HEAD", "--"],
         ["git", "ls-files", "--others", "--exclude-standard"],
     ]
     out: Set[str] = set()

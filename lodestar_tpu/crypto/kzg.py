@@ -19,7 +19,7 @@ via `TrustedSetup.from_points`.
 The MSM here runs on the CPU oracle (correctness path).  At mainnet
 blob scale the MSM is the same gather + randomizer + jacobian-sum
 machinery the TPU BLS pipeline already implements (kernels/verify.py
-`_k_agg_pk` / `_j_seg_sum_g1`) — wiring blobs through it is the
+`_k_agg_pk` / `_seg_sum_g1`) — wiring blobs through it is the
 device-acceleration path once blob throughput matters.
 """
 

@@ -10,17 +10,14 @@ API usable (no durability).
 from __future__ import annotations
 
 import ctypes
-import os
 from typing import Iterator, List, Optional, Tuple
 
-_NATIVE_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "native",
-    "libkvstore.so",
-)
+from ..native import build
+
+_NATIVE_PATH = build("libkvstore.so")
 
 _lib: Optional[ctypes.CDLL] = None
-if os.path.exists(_NATIVE_PATH):
+if _NATIVE_PATH is not None:
     try:
         _lib = ctypes.CDLL(_NATIVE_PATH)
         _lib.kv_open.argtypes = [ctypes.c_char_p]

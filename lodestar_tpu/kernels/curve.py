@@ -75,6 +75,12 @@ FP2_OPS = FieldOps(
 )
 
 
+def pick(cond, a, b):
+    """Select between two pytrees of planes on one traced scalar
+    condition (a rolled loop's step index picking its operands)."""
+    return jax.tree_util.tree_map(lambda x, y: jnp.where(cond, x, y), a, b)
+
+
 def select_pt(fo: FieldOps, mask, p, q):
     return tuple(fo.select(mask, a, b) for a, b in zip(p, q))
 
@@ -264,15 +270,30 @@ def scalar_mul_window_jac(
     """
     assert nbits % w == 0, (nbits, w)
     assert w >= 1
-    # multiple table: tbl[m-1] = m*Q for m in 1..2^w-1.  Even entries
+    # multiple table: tbl[m-1] = m*Q for m in 1..2^w-1, built in ONE
+    # rolled loop: unrolled, its 2^w-2 point operations were ~4/5 of the
+    # kernel's code and cold compile (PERF.md, PR 21).  Even entries
     # double the half entry; odd entries add Q to the previous entry
-    # (m*Q == ±Q needs m ≡ ±1 mod r — impossible for 2 <= m < 2^w).
-    tbl = [q]
-    for m in range(2, 1 << w):
-        if m % 2 == 0:
-            tbl.append(jac_dbl(fo, tbl[m // 2 - 1]))
-        else:
-            tbl.append(jac_add_mixed_or_full(fo, tbl[m - 2], q))
+    # (m*Q == ±Q needs m ≡ ±1 mod r — impossible for 2 <= m < 2^w);
+    # each step computes both and its parity selects.
+    n_tbl = (1 << w) - 1
+
+    def entry(tbl, idx):
+        m = tbl[0]
+        for v in range(1, n_tbl):
+            m = pick(idx == v, tbl[v], m)
+        return m
+
+    def build(i, tbl):
+        m = i + 2
+        new = pick(
+            m % 2 == 0,
+            jac_dbl(fo, entry(tbl, m // 2 - 1)),
+            jac_add_mixed_or_full(fo, entry(tbl, m - 2), q),
+        )
+        return tuple(pick(m - 1 == k, new, tbl[k]) for k in range(n_tbl))
+
+    tbl = lax.fori_loop(0, n_tbl - 1, build, (q,) * n_tbl)
 
     def digit_multiple(d):
         """tbl[d] for d in 1..2^w-1 as a masked-select chain (d == 0
@@ -284,8 +305,7 @@ def scalar_mul_window_jac(
 
     def body(t, st):
         (T, t_inf) = st
-        for _ in range(w):
-            T = jac_dbl(fo, T)
+        T = lax.fori_loop(0, w, lambda _, T: jac_dbl(fo, T), T)
         d = get_digit(t)
         add = digit_multiple(d)
         cand = jac_add_mixed_or_full(fo, T, add)
@@ -406,34 +426,84 @@ def sum_points_axis0(fo: FieldOps, pts, inf):
     )
 
 
-def sum_points_lanes(fo: FieldOps, pts, inf, roll_fn=jnp.roll):
+def sum_points_lanes(fo: FieldOps, pts, inf):
     """Butterfly-sum over the LANE (batch, last) axis -> FULL width.
 
     Uses full-width lane rolls instead of halving lane slices: narrow or
     offset lane slices produce Mosaic layouts that later sublane pads
     reject ("result/input offset mismatch on non-concat dimension"), and
     on a 128-lane VPU a half-width op costs the same as a full-width one
-    anyway.  log2(B) rounds of jac_add_full; EVERY lane ends up holding
-    the total (read any one).  B must be a power of two (the pipeline's
-    lane tile BT = 128 is).  Inside pallas kernels pass
-    roll_fn=pltpu.roll (the supported lane-rotate primitive there).
+    anyway.  log2(B) rounds of jac_add_full in ONE rolled loop (a traced
+    shift), so the compiled program holds one addition, not log2(B);
+    EVERY lane ends up holding the total (read any one).  B must be a
+    power of two (the pipeline's lane tile BT = 128 is).  Runs inside a
+    pallas kernel or under plain jit (lane_roll lowers either way).
     """
     b = inf.shape[-1]
     assert b & (b - 1) == 0, f"lane width {b} must be a power of two"
-    inf_i = inf.astype(jnp.int32)
-    shift = b // 2
-    while shift >= 1:
+
+    def body(r, st):
+        pts, inf_i = st
+        shift = jnp.right_shift(jnp.int32(b // 2), r)
         other = jax.tree_util.tree_map(
-            lambda a: roll_fn(a, shift, axis=-1), pts
+            lambda a: lane_roll(a, shift), pts
         )
         # lift 1-D lane masks to 2-D for the rotate (TPU prefers >= 2-D)
-        other_inf = roll_fn(inf_i[None, :], shift, axis=-1)[0]
+        other_inf = lane_roll(inf_i[None, :], shift)[0]
         pts, s_inf = jac_add_full(
             fo, pts, inf_i != 0, other, other_inf != 0
         )
-        inf_i = s_inf.astype(jnp.int32)
-        shift //= 2
+        return pts, s_inf.astype(jnp.int32)
+
+    pts, inf_i = lax.fori_loop(
+        0, b.bit_length() - 1, body, (pts, inf.astype(jnp.int32))
+    )
     return pts, inf_i != 0
+
+
+def lane_roll(a, shift):
+    """jnp.roll over the lane (last) axis that also lowers inside a
+    pallas kernel (pltpu.roll; the shift may be traced)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.roll(a, shift, a.ndim - 1)
+
+
+def seg_sum_lanes(fo: FieldOps, pts, dead, group, carry):
+    """Segmented inclusive jacobian prefix-scan over the lane axis.
+
+    `group` is int32[1, n], nondecreasing (lane-contiguous segments);
+    `dead` int32[1, n] lanes count as infinity.  log2(n) rounds of
+    jac_add_full in one rolled loop; the LAST lane of each segment
+    holds its total.  `carry` = (planes, ok bool[1, n]) is one first
+    round of the same loop: lanes where ok add the carry planes (a
+    running total handed in from a previous lane tile).  Returns
+    (planes, inf bool[n]).  n must be a power of two.
+    """
+    n = group.shape[-1]
+    assert n & (n - 1) == 0, f"lane width {n} must be a power of two"
+    lane = lax.broadcasted_iota(jnp.int32, group.shape, group.ndim - 1)
+
+    def body(r, st):
+        pts, inf_i = st
+        s = jnp.left_shift(jnp.int32(1), jnp.maximum(r, 0))
+        prev = jax.tree_util.tree_map(lambda a: lane_roll(a, s), pts)
+        # a lane adds its predecessor s lanes back only within its own
+        # segment (and not across the wrap-around)
+        skip = (lane_roll(lane, s) != lane - s) | (
+            lane_roll(group, s) != group
+        ) | (lane_roll(inf_i, s) != 0)
+        # round -1 adds the carry instead (an int32 select: Mosaic does
+        # not select i1 vectors)
+        prev = pick(r < 0, carry[0], prev)
+        skip = jnp.where(
+            r < 0, (~carry[1]).astype(jnp.int32), skip.astype(jnp.int32)
+        ) != 0
+        pts, t_inf = jac_add_full(fo, pts, inf_i[0] != 0, prev, skip[0])
+        return pts, t_inf[None, :].astype(jnp.int32)
+
+    pts, inf_i = lax.fori_loop(-1, n.bit_length() - 1, body, (pts, dead))
+    return pts, inf_i[0] != 0
 
 
 # ---------------------------------------------------------------------------

@@ -1,13 +1,15 @@
 """AOT export cache — trace once, reload without re-tracing.
 
 The wire verify pipeline unrolls 33-limb schoolbook arithmetic into a
-~1e5-equation jaxpr; TRACING it costs ~10 minutes per process on the
-1-core driver host (dev/NOTES.md "CPU-host costs") while the actual
-XLA/Mosaic compile is served by the persistent compile cache.  Tracing
-is pure Python work over static shapes, so it can be paid ONCE, the
-result serialized with `jax.export`, and every later process —
-including the driver's bench window — deserializes in milliseconds and
-goes straight to (cached) compilation.
+large jaxpr; TRACING it costs minutes of host time per process, while
+the XLA/Mosaic compile can be served by the persistent compile cache.
+Tracing is pure Python work over static shapes, so it can be paid
+ONCE, the result serialized with `jax.export`, and every later process
+deserializes in milliseconds and goes straight to (cached) compilation.
+The BLS verifier does not dispatch through it (PR 21): an artifact is
+one monolithic program per (entry, shape), so a COLD start compiles
+every stage kernel again for each shape, where direct dispatch
+compiles each stage once per process (PERF.md).
 
 Artifacts are keyed by (entry name, shape/dtype signature, platform,
 jax version, kernels-code fingerprint); a stale fingerprint falls back
@@ -28,8 +30,11 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 
+# a fixed directory inside the checkout (gitignored), so a later
+# process on the same checkout finds what an earlier one wrote
 DEFAULT_DIR = os.environ.get(
-    "LODESTAR_TPU_EXPORT_CACHE", "/tmp/lodestar_tpu_export_cache"
+    "LODESTAR_TPU_EXPORT_CACHE",
+    str(pathlib.Path(__file__).resolve().parents[2] / ".export_cache"),
 )
 
 # in-process cache of deserialized/exported entries
@@ -224,8 +229,7 @@ class ExportStageError(RuntimeError):
     `.entry` name WHERE the artifact layer died, and the cause's text
     is embedded so the breaker supervisor's failure classifier
     (bls/supervisor.py classify_failure) can tell a backend-init death
-    — the r03–r05 180 s probe failures happened exactly here — from a
-    mere stale-artifact problem (ISSUE 14)."""
+    from a mere stale-artifact problem (ISSUE 14)."""
 
     def __init__(self, stage: str, entry: str, cause: BaseException):
         super().__init__(

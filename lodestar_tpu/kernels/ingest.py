@@ -175,9 +175,30 @@ def clear_cofactor_g2(q_aff):
 
 def hash_to_g2_values(u0, u1, u0_sgn0, u1_sgn0):
     """Full map_to_curve for one message: two SSWU points, added on the
-    isogenous image, cofactor-cleared.  Returns jacobian planes + ok."""
-    q0, ok0 = iso3_map(sswu_map_g2(u0, u0_sgn0))
-    q1, ok1 = iso3_map(sswu_map_g2(u1, u1_sgn0))
+    isogenous image, cofactor-cleared.  Returns jacobian planes + ok.
+
+    The two maps run as one rolled 2-step loop, so the kernel holds the
+    SSWU + isogeny code once (unrolled it was most of the kernel's cold
+    compile, PERF.md PR 21); ok flags ride the carry as int32 (Mosaic
+    rejects i1 loop carries)."""
+
+    def one_map(i, st):
+        q0, ok0, q1, ok1 = st
+        first = i == 0
+        q, ok = iso3_map(
+            sswu_map_g2(CV.pick(first, u0, u1), CV.pick(first, u0_sgn0, u1_sgn0))
+        )
+        ok = ok.astype(jnp.int32)
+        return (
+            CV.pick(first, q, q0), CV.pick(first, ok, ok0),
+            CV.pick(first, q1, q), CV.pick(first, ok1, ok),
+        )
+
+    no = jnp.zeros(u0[0].shape[-1:], jnp.int32)
+    q0, ok0, q1, ok1 = jax.lax.fori_loop(
+        0, 2, one_map, ((u0, u0), no, (u0, u0), no)
+    )
+    ok0, ok1 = ok0 != 0, ok1 != 0
     # q0 + q1 (affine-affine via mixed jacobian add; q0 == +-q1 has
     # negligible probability for hash outputs)
     one = CV._one_plane_like(CV.FP2_OPS, q0[0])

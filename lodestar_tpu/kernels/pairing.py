@@ -185,51 +185,66 @@ def miller_loop(p_jac, q_aff):
     return TW.conj12(f)
 
 
-def product12_lanes(f, valid, roll_fn=jnp.roll):
+def product12_lanes(f):
     """Product of f's lanes over the batch axis -> FULL width.
 
-    Butterfly over full-width lane rolls (log2(B) mul12 rounds) rather
-    than halving lane slices: narrow/offset lane slices produce Mosaic
-    layouts later sublane pads reject, and half-width ops are not
-    cheaper on the 128-lane VPU.  EVERY lane of the result holds the
-    product; B must be a power of two (the lane tile BT = 128 is).
-    Inside pallas kernels pass roll_fn=pltpu.roll.
+    Butterfly over full-width lane rolls (log2(B) mul12 rounds in ONE
+    rolled loop with a traced shift) rather than halving lane slices:
+    narrow/offset lane slices produce Mosaic layouts later sublane pads
+    reject, and half-width ops are not cheaper on the 128-lane VPU.
+    EVERY lane of the result holds the product; B must be a power of
+    two (the lane tile BT = 128 is).  Runs inside a pallas kernel or
+    under plain jit (curve.lane_roll lowers either way).
     """
-    one = TW.one12(f[0][0][0])
-    f = TW.select12(valid, f, one)
-    b = valid.shape[-1]
+    b = f[0][0][0].shape[-1]
     assert b & (b - 1) == 0, f"lane width {b} must be a power of two"
-    shift = b // 2
-    while shift >= 1:
-        other = jax.tree_util.tree_map(
-            lambda a: roll_fn(a, shift, axis=-1), f
-        )
-        f = TW.mul12(f, other)
-        shift //= 2
-    return f
+
+    def body(r, f):
+        shift = jnp.right_shift(jnp.int32(b // 2), r)
+        other = jax.tree_util.tree_map(lambda a: CV.lane_roll(a, shift), f)
+        return TW.mul12(f, other)
+
+    return lax.fori_loop(0, b.bit_length() - 1, body, f)
 
 
 def final_exponentiation(f):
-    """f^(3 (p^12-1)/r) — see module docstring for the cube."""
+    """f^(3 (p^12-1)/r) — see module docstring for the cube.
+
+    The hard part's five x-powers run as ONE rolled loop, each step
+    r_i = r_{i-1}^x * c_i with a step-selected factor c_i, and its
+    closing product as a second loop of one Fp12 multiply: the kernel
+    holds one power and two multiplies where the straight-line chain
+    held five and nine (its compile time was the largest on the cold
+    start, PERF.md PR 21).
+    """
     # easy part: m = (conj(f) * f^-1)^(p^2) * (conj(f) * f^-1)
     g = TW.mul12(TW.conj12(f), TW.inv12(f))
     m = TW.mul12(TW.frob12(g, 2), g)
-    # hard part ((x-1)^2 (x+p) (x^2+p^2-1) + 3 chain)
-    t0 = TW.cyclo_sqr(m)                      # m^2
-    t1 = TW.cyclo_pow_x_neg(m)                # m^x
-    t1 = TW.mul12(t1, TW.conj12(m))           # m^(x-1)
-    t2 = TW.cyclo_pow_x_neg(t1)               # ^x
-    t1 = TW.mul12(TW.conj12(t1), t2)          # m^((x-1)^2)
-    t2 = TW.cyclo_pow_x_neg(t1)               # ^x
-    t1 = TW.frob12(t1, 1)                     # ^p
-    t1 = TW.mul12(t1, t2)                     # m^((x-1)^2 (p+x))
-    m3 = TW.mul12(m, t0)                      # m^3
-    t0 = TW.cyclo_pow_x_neg(t1)               # ^x
-    t2 = TW.cyclo_pow_x_neg(t0)               # ^x^2
-    t0 = TW.frob12(t1, 2)                     # ^p^2
-    t1 = TW.mul12(TW.conj12(t1), t2)          # ^(x^2 - 1)
-    t1 = TW.mul12(t1, t0)                     # ^(x^2 + p^2 - 1)
-    return TW.mul12(t1, m3)
+    one = TW.one12(m[0][0][0])
+
+    # hard part ((x-1)^2 (x+p) (x^2+p^2-1) + 3 chain):
+    #   r1 = m^x conj(m)          = m^(x-1)
+    #   r2 = r1^x conj(r1)        = m^((x-1)^2)
+    #   r3 = r2^x r2^p            = m^((x-1)^2 (x+p))
+    #   r4 = r3^x,  r5 = r4^x     = r3^(x^2)
+    def power_step(i, st):
+        x, r3 = st
+        c = CV.pick(i == 2, TW.frob12(x, 1), TW.conj12(x))
+        c = CV.pick(i >= 3, one, c)
+        r = TW.mul12(TW.cyclo_pow_x_neg(x), c)
+        return r, CV.pick(i == 2, r, r3)
+
+    r5, r3 = lax.fori_loop(0, 5, power_step, (m, m))
+    # r3^(x^2 + p^2 - 1) * m^3 = r5 * r3^(p^2) * conj(r3) * m * m^2
+    factors = (TW.frob12(r3, 2), TW.conj12(r3), m, TW.cyclo_sqr(m))
+
+    def close_step(i, acc):
+        c = factors[-1]
+        for k in range(len(factors) - 2, -1, -1):
+            c = CV.pick(i == k, factors[k], c)
+        return TW.mul12(acc, c)
+
+    return lax.fori_loop(0, len(factors), close_step, r5)
 
 
 def to_affine_g2(pt_jac):

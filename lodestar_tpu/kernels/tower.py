@@ -241,41 +241,37 @@ def cyclo_sqr(a):
     return (c0, c1)
 
 
-def _pow_loop(acc, base, word: int, nbits: int, sqr_fn, mul_fn):
-    """nbits MSB-first square-and-multiply steps for one static 32-bit word.
+def pow_static(x, e: int, sqr_fn, mul_fn, one):
+    """x^e for a static python int e >= 1.
 
-    The bit is extracted from the static python word with a traced shift —
-    no dynamic array indexing, so this lowers cleanly in Mosaic.
+    ONE rolled square-and-multiply loop over the bits below the leading
+    one, MSB first, so the compiled program holds one square and one
+    multiply whatever the exponent's length (a loop per 32-bit word
+    multiplied the kernel's code, and its compile time, by the word
+    count).  Each step's bit comes from the static exponent words
+    through a traced shift and a select chain — no dynamic array
+    indexing, so this lowers cleanly in Mosaic.
     """
-    w = jnp.uint32(word)
+    assert e >= 1
+    nbits = e.bit_length() - 1
+    if nbits == 0:
+        return x
+    words = [(e >> (32 * k)) & 0xFFFFFFFF for k in range((nbits + 31) // 32)]
 
     def body(i, acc):
         acc = sqr_fn(acc)
-        bit = (w >> (jnp.uint32(nbits - 1) - jnp.uint32(i))) & jnp.uint32(1)
-        cand = mul_fn(acc, base)
+        p = jnp.int32(nbits - 1) - i  # bit position, LSB = 0
+        wi = jnp.right_shift(p, 5)
+        word = jnp.uint32(words[0])
+        for k in range(1, len(words)):
+            word = jnp.where(wi == k, jnp.uint32(words[k]), word)
+        bit = jnp.right_shift(word, (p & 31).astype(jnp.uint32)) & jnp.uint32(1)
+        cand = mul_fn(acc, x)
         return jax.tree_util.tree_map(
             lambda c, a: jnp.where(bit != 0, c, a), cand, acc
         )
 
-    return lax.fori_loop(0, nbits, body, acc)
-
-
-def pow_static(x, e: int, sqr_fn, mul_fn, one):
-    """x^e for a static python int e >= 1 via per-word rolled loops."""
-    assert e >= 1
-    bits = e.bit_length()
-    # Leading word: start acc at x and consume remaining bits of that word.
-    nbits = (bits - 1) % 32
-    acc = x
-    top_word = e >> (bits - 1 - nbits) if nbits else None
-    if nbits:
-        acc = _pow_loop(acc, x, top_word & ((1 << nbits) - 1), nbits, sqr_fn, mul_fn)
-    rest = (bits - 1) - nbits
-    assert rest % 32 == 0
-    for k in range(rest // 32 - 1, -1, -1):
-        word = (e >> (32 * k)) & 0xFFFFFFFF
-        acc = _pow_loop(acc, x, word, 32, sqr_fn, mul_fn)
-    return acc
+    return lax.fori_loop(0, nbits, body, x)
 
 
 _X_ABS = -GT.X_PARAM  # 0xd201000000010000

@@ -16,18 +16,15 @@ module adds:
 from __future__ import annotations
 
 import ctypes
-import os
 import struct
 from typing import Optional, Tuple
 
-_LIB_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "native",
-    "libsnappy_tpu.so",
-)
+from ..native import build
+
+_LIB_PATH = build("libsnappy_tpu.so")
 
 _lib: Optional[ctypes.CDLL] = None
-if os.path.exists(_LIB_PATH):
+if _LIB_PATH is not None:
     try:
         _lib = ctypes.CDLL(_LIB_PATH)
         _lib.snappy_compress.restype = ctypes.c_size_t

@@ -19,14 +19,12 @@ import hashlib
 import os
 from typing import Optional
 
-_NATIVE_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "native",
-    "libsha256_batch.so",
-)
+from ..native import build
+
+_NATIVE_PATH = build("libsha256_batch.so")
 
 _native: Optional[ctypes.CDLL] = None
-if os.path.exists(_NATIVE_PATH):
+if _NATIVE_PATH is not None:
     try:
         _native = ctypes.CDLL(_NATIVE_PATH)
         _native.sha256_hash_pairs.argtypes = [

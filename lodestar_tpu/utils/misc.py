@@ -50,36 +50,51 @@ class DeadlineExceeded(Exception):
     """run_with_deadline's fn did not return within its deadline."""
 
 
+class DeadlineRunner:
+    """`fn()` running on ONE expendable daemon thread; `wait(timeout)`
+    raises DeadlineExceeded when it has not returned by then.  A stalled
+    thread is abandoned — the caller moves on, and the caller's deadline
+    measures ONLY its own call (no shared-worker queue wait)."""
+
+    def __init__(self, fn: Callable[[], T], desc: str = "call"):
+        self.desc = desc
+        self._result: Dict[str, object] = {}
+        self._done = threading.Event()
+        self._t0 = time.monotonic()
+        threading.Thread(
+            target=self._run, args=(fn,), daemon=True, name="deadline-runner"
+        ).start()
+
+    def _run(self, fn) -> None:
+        try:
+            self._result["value"] = fn()
+        except BaseException as e:  # noqa: BLE001 — transported to caller
+            self._result["error"] = e
+        finally:
+            self._done.set()
+
+    def elapsed(self) -> float:
+        return time.monotonic() - self._t0
+
+    def wait(self, timeout_s: float):
+        if not self._done.wait(timeout=max(timeout_s, 0.0)):
+            raise DeadlineExceeded(
+                f"{self.desc} did not return within {timeout_s:g}s"
+            )
+        if "error" in self._result:
+            raise self._result["error"]  # type: ignore[misc]
+        return self._result.get("value")
+
+
 def run_with_deadline(
     fn: Callable[[], T], timeout_s: float, desc: str = "call"
 ) -> T:
-    """Run `fn()` on ONE expendable daemon thread; raise
+    """Run `fn()` on an expendable thread (DeadlineRunner); raise
     DeadlineExceeded when it does not return within `timeout_s`.  The
-    stalled thread is abandoned — the caller moves on, and the caller's
-    deadline measures ONLY its own call (no shared-worker queue wait).
-    The single shared bounded-wait runner (ISSUE 14): the BLS breaker's
+    single shared bounded-wait runner (ISSUE 14): the BLS breaker's
     watchdog and the req/resp stall timeout both wrap it with their own
     exception types."""
-    result: Dict[str, object] = {}
-    done = threading.Event()
-
-    def _run():
-        try:
-            result["value"] = fn()
-        except BaseException as e:  # noqa: BLE001 — transported to caller
-            result["error"] = e
-        finally:
-            done.set()
-
-    t = threading.Thread(target=_run, daemon=True, name="deadline-runner")
-    t.start()
-    if not done.wait(timeout=timeout_s):
-        raise DeadlineExceeded(
-            f"{desc} did not return within {timeout_s:g}s"
-        )
-    if "error" in result:
-        raise result["error"]  # type: ignore[misc]
-    return result.get("value")  # type: ignore[return-value]
+    return DeadlineRunner(fn, desc).wait(timeout_s)  # type: ignore[return-value]
 
 
 def retry(

@@ -37,18 +37,15 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import pickle
 import time
 
 os.environ.setdefault("XLA_FLAGS", "")
 
 import numpy as np
 
-import jax
+from lodestar_tpu.utils import compile_cache  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/lodestar_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+compile_cache.configure()
 
 from lodestar_tpu import params
 from lodestar_tpu.bls.pubkey_table import PubkeyTable
@@ -61,18 +58,8 @@ from lodestar_tpu.network.gossip_queues import GossipType
 from lodestar_tpu.network.processor import NetworkProcessor, PendingGossipMessage
 from lodestar_tpu.state_transition.util import compute_committee_count_per_slot
 
-CACHE = "/tmp/lodestar_tpu_replay_cache.pkl"
-
-
 def build_world(n_validators: int, distinct_keys: int, slots: int):
-    """Keys, table, and per-(key, root) signatures; disk-cached."""
-    # v2: wire format (compressed signature bytes, padded roots)
-    key = ("wire-v2", n_validators, distinct_keys, slots)
-    if os.path.exists(CACHE):
-        with open(CACHE, "rb") as f:
-            cached = pickle.load(f)
-        if cached.get("key") == key:
-            return cached
+    """Keys, table, and per-(key, root) signatures."""
     sks = [B.keygen(b"replay-%d" % i) for i in range(distinct_keys)]
     pks = [B.sk_to_pk(sk) for sk in sks]
 
@@ -91,16 +78,12 @@ def build_world(n_validators: int, distinct_keys: int, slots: int):
         roots[(slot, "sync")] = sync_root
         for k in range(distinct_keys):
             sigs[(k, slot, "sync")] = g2_compress(B.sign(sks[k], sync_root))
-    world = {
-        "key": key,
+    return {
         "pks": pks,
         "committees": committees,
         "roots": roots,
         "sigs": sigs,
     }
-    with open(CACHE, "wb") as f:
-        pickle.dump(world, f)
-    return world
 
 
 def main():

@@ -171,7 +171,7 @@ class ChaosVerifier(TpuBlsVerifier):
             raise RuntimeError("injected device fault (chaos)")
         if mode == "backend":
             raise RuntimeError(
-                "injected: TPU backend UNAVAILABLE, tunnel down"
+                "injected: TPU backend UNAVAILABLE, device lost"
             )
         if mode == "hang":
             # blocks until released or the watchdog deadline fires (the

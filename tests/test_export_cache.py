@@ -144,27 +144,6 @@ def test_cross_platform_tpu_export_from_cpu_host(tmp_path):
     assert EC.load("mosaic-x", specs, "tpu", cache_dir=str(tmp_path)) is not None
 
 
-def test_verifier_export_dispatch_fallback(monkeypatch):
-    """_device_call never lets the export layer break verification."""
-    from lodestar_tpu.bls.pubkey_table import PubkeyTable
-    from lodestar_tpu.bls.verifier import TpuBlsVerifier
-    from lodestar_tpu.crypto import bls as B
-
-    pks = [B.sk_to_pk(B.keygen(b"ec-%d" % i)) for i in range(4)]
-    table = PubkeyTable(capacity=8)
-    table.register_points_unchecked(pks, tile_to=8)
-    v = TpuBlsVerifier(table)
-    v._use_export = True
-
-    def boom(*a, **k):
-        raise RuntimeError("export layer down")
-
-    monkeypatch.setattr(EC, "load_or_export", boom)
-    # falls back to the direct path and still verifies
-    out = v._device_call("x", lambda a, b: a + b, (jnp.ones(2), jnp.ones(2)))
-    assert np.allclose(np.asarray(out), 2.0)
-
-
 def test_staged_artifacts_match_verifier_contract():
     """When the staged TPU artifacts exist (driver host), their input
     signature must match what the verifier dispatches at bench shapes —
@@ -366,7 +345,7 @@ def test_export_stage_error_carries_stage_and_classifies():
     )
 
     def dead_backend(_x):
-        raise RuntimeError("TPU backend UNAVAILABLE: tunnel down")
+        raise RuntimeError("TPU backend UNAVAILABLE: device lost")
 
     spec = jax.ShapeDtypeStruct((4,), "int32")
     with pytest.raises(ExportStageError) as ei:

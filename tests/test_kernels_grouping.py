@@ -54,7 +54,7 @@ def test_segmented_g1_sum_matches_oracle():
     px = jnp.asarray(LY.encode_batch([p[0] for p in pts]))
     py = jnp.asarray(LY.encode_batch([p[1] for p in pts]))
     pz = jnp.asarray(LY.encode_batch([1] * n))
-    out_pts, out_inf = KV._j_seg_sum_g1(
+    out_pts, out_inf = KV._seg_sum_g1(
         px, py, pz, jnp.asarray(dead), jnp.asarray(group)
     )
     decoded = _jac_decode(out_pts)
@@ -72,10 +72,45 @@ def test_segmented_g1_sum_matches_oracle():
         assert decoded[head] == want, f"head lane {head}"
     # an all-dead segment sums to infinity
     dead2 = np.ones(n, bool)
-    _, inf2 = KV._j_seg_sum_g1(
+    _, inf2 = KV._seg_sum_g1(
         px, py, pz, jnp.asarray(dead2), jnp.asarray(group)
     )
     assert all(np.asarray(inf2))
+
+
+@pytest.mark.smoke
+@pytest.mark.parametrize(
+    "bounds, dead_lanes",
+    [
+        # one segment through all three tiles, across dead first lanes
+        ((0, 100, 300, 384), (128, 256, 301)),
+        # segments ending and starting exactly on tile boundaries
+        ((0, 128, 256, 384), (5,)),
+    ],
+)
+def test_segmented_g1_sum_carries_across_tiles(bounds, dead_lanes):
+    """_seg_scan runs one BT-lane tile at a time and carries each
+    tile's running total into the next: segment totals at head lanes
+    == the host oracle for segments that span tiles."""
+    n = 3 * KV.BT
+    pts = [GC.scalar_mul(GC.FP_OPS, GC.G1_GEN, 3 + i) for i in range(n)]
+    group = np.zeros(n, np.int32)
+    for g, (lo, hi) in enumerate(zip(bounds, bounds[1:])):
+        group[lo:hi] = g
+    dead = np.zeros(n, bool)
+    dead[list(dead_lanes)] = True
+    enc = lambda v: jnp.asarray(LY.encode_batch(v))  # noqa: E731
+    out_pts, out_inf = KV._seg_sum_g1(
+        enc([p[0] for p in pts]), enc([p[1] for p in pts]), enc([1] * n),
+        jnp.asarray(dead), jnp.asarray(group),
+    )
+    decoded = _jac_decode(out_pts)
+    assert not np.asarray(out_inf)[[hi - 1 for hi in bounds[1:]]].any()
+    for lo, hi in zip(bounds, bounds[1:]):
+        want = GC.multi_add(
+            GC.FP_OPS, [pts[i] for i in range(lo, hi) if not dead[i]]
+        )
+        assert decoded[hi - 1] == want, f"segment [{lo}, {hi})"
 
 
 def _jac_decode_g2(planes):
@@ -103,10 +138,61 @@ def _jac_decode_g2(planes):
     return out
 
 
+@pytest.mark.smoke
+def test_lane_sum_kernel_matches_oracle():
+    """_k_sum_lanes (the batch path's cross-lane G2 butterfly, a rolled
+    loop over a traced pltpu.roll shift): every lane holds the sum of
+    the live lanes."""
+    bt, nl = KV.BT, KV.NL
+    pts = [GC.scalar_mul(GC.FP2_OPS, GC.G2_GEN, 3 + 2 * i) for i in range(bt)]
+    inf = np.zeros((1, bt), np.int32)
+    inf[0, 5] = 1
+    planes = [
+        jnp.asarray(LY.encode_batch(v))
+        for v in (
+            [p[0][0] for p in pts], [p[0][1] for p in pts],
+            [p[1][0] for p in pts], [p[1][1] for p in pts],
+            [1] * bt, [0] * bt,
+        )
+    ]
+    out = KV._tiled(
+        KV._k_sum_lanes, (*planes, jnp.asarray(inf)),
+        [nl] * 6 + [1], [nl] * 6 + [1], bt,
+    )
+    want = GC.multi_add(GC.FP2_OPS, [p for i, p in enumerate(pts) if i != 5])
+    decoded = _jac_decode_g2(out[:6])
+    assert decoded[0] == want and decoded[77] == want
+    assert not np.asarray(out[6]).any()
+
+
+@pytest.mark.smoke
+def test_lane_product_kernel_matches_oracle():
+    """_k_prod_lanes (the batch path's cross-lane Fp12 product): every
+    lane holds the product of all lanes."""
+    bt, nl = KV.BT, KV.NL
+    rng = random.Random(3)
+    vals = [[rng.randrange(LY.P) for _ in range(bt)] for _ in range(12)]
+    planes = tuple(jnp.asarray(LY.encode_batch(v)) for v in vals)
+    out = KV._tiled(KV._k_prod_lanes, planes, [nl] * 12, [nl] * 12, bt)
+    dec = [LY.decode_batch(np.asarray(p)) for p in out]
+
+    def lane(src, i):
+        g = [src[k][i] for k in range(12)]
+        return (
+            ((g[0], g[1]), (g[2], g[3]), (g[4], g[5])),
+            ((g[6], g[7]), (g[8], g[9]), (g[10], g[11])),
+        )
+
+    want = lane(vals, 0)
+    for i in range(1, bt):
+        want = GF.fp12_mul(want, lane(vals, i))
+    assert lane(dec, 0) == want and lane(dec, 99) == want
+
+
 @pytest.mark.slow
 def test_segmented_g2_sum_matches_oracle():
     """The pre-verify aggregation stage's G2 scan (ISSUE 13,
-    KV._j_seg_sum_g2): segment totals at head lanes == the host
+    KV._seg_sum_g2): segment totals at head lanes == the host
     jacobian-add oracle, dead lanes excluded, all-dead segments at
     infinity — the FP2 twin of the G1 test above, at tiny width.
 
@@ -124,7 +210,7 @@ def test_segmented_g2_sum_matches_oracle():
     px1 = jnp.asarray(LY.encode_batch([p[0][1] for p in pts]))
     py0 = jnp.asarray(LY.encode_batch([p[1][0] for p in pts]))
     py1 = jnp.asarray(LY.encode_batch([p[1][1] for p in pts]))
-    out = KV._j_seg_sum_g2(
+    out = KV._seg_sum_g2(
         px0, px1, py0, py1, jnp.asarray(dead), jnp.asarray(group)
     )
     decoded = _jac_decode_g2(out[:6])
@@ -140,7 +226,7 @@ def test_segmented_g2_sum_matches_oracle():
         assert not inf[head]
         assert decoded[head] == want, f"head lane {head}"
     dead2 = np.ones(n, bool)
-    out2 = KV._j_seg_sum_g2(
+    out2 = KV._seg_sum_g2(
         px0, px1, py0, py1, jnp.asarray(dead2), jnp.asarray(group)
     )
     assert all(np.asarray(out2[6]))
@@ -298,7 +384,7 @@ def test_group_heads_gather_and_liveness():
     group = np.asarray([0, 0, 1, 1, 2, 2, 3, 3], np.int32)
     dead = np.zeros(n, bool)
     dead[4] = dead[5] = True  # group 2 entirely dead
-    seg_pts, seg_inf = KV._j_seg_sum_g1(
+    seg_pts, seg_inf = KV._seg_sum_g1(
         px, py, pz, jnp.asarray(dead), jnp.asarray(group)
     )
 

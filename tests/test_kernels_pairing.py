@@ -125,7 +125,7 @@ def test_signature_relation_and_batch_product():
     @jax.jit
     def f(px, py, qx, qy):
         ml = KP.miller_loop((px, py, one1), (qx, qy))
-        prod = KP.product12_lanes(ml, jnp.ones((4,), bool))
+        prod = KP.product12_lanes(ml)
         fe = KP.final_exponentiation(prod)
         return TW.is_one12(fe)
 

@@ -293,3 +293,22 @@ def test_fault_degrades_to_host_and_root_survives(backend):
     assert backend.hash_level(pairs) is None
     plane = hash_pairs_plane(pairs)  # the seam falls through to host
     np.testing.assert_array_equal(plane, _host_digests(pairs))
+
+
+def test_bench_device_state_roots_probe_runs_in_process(capsys, monkeypatch):
+    """bench.py measures state_roots_per_s_device in its own process (a
+    child could not take the chip the parent holds): the probe switches
+    the HTR backend on for itself only and emits one record."""
+    import json
+    import os
+
+    import bench
+
+    monkeypatch.setattr(bench, "BENCH_HTR_VALIDATORS", 2048)
+    monkeypatch.delenv("LODESTAR_TPU_HTR_BACKEND", raising=False)
+    bench._probe_state_roots_device()
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rec["metric"] == "state_roots_per_s_device"
+    assert rec["value"] > 0 and rec["htr"]["dispatches"] > 0
+    assert "LODESTAR_TPU_HTR_BACKEND" not in os.environ
+    assert DB.maybe_backend() is None
