@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
+from contextlib import nullcontext
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -26,6 +27,7 @@ import numpy as np
 from ..crypto import fields as GT
 from ..crypto import hash_to_curve as HC
 from ..kernels import layout as LY
+from ..observability import timed_span
 
 P = GT.P
 BT = 128
@@ -40,11 +42,18 @@ class MessageCache:
 
     Misses are hashed in one padded device batch per `get_many` call;
     an LRU bound keeps the cache sized to a few slots of distinct
-    attestation/sync data.
+    attestation/sync data.  With `metrics` (the verifier's
+    BlsPoolMetrics), each device batch times its three host steps into
+    `lodestar_bls_job_host_step_seconds{step=field|device_hash|affine}`
+    and, when tracing is on, the `bls.msg.*` spans.
     """
 
     def __init__(
-        self, max_entries: int = 4096, use_device: bool = True, dispatch=None
+        self,
+        max_entries: int = 4096,
+        use_device: bool = True,
+        dispatch=None,
+        metrics=None,
     ):
         self.max_entries = max_entries
         self.use_device = use_device
@@ -52,6 +61,7 @@ class MessageCache:
         # passes its _device_call, which keeps the breaker's watchdog
         # off a width's first (compiling) dispatch
         self._dispatch = dispatch or (lambda _name, fn, args: fn(*args))
+        self._steps = metrics.job_host_step if metrics is not None else None
         self._cache: "OrderedDict[bytes, Tuple]" = OrderedDict()
         # the service's dispatcher and resolver threads both reach the
         # cache (retry path); all OrderedDict mutation happens under here
@@ -88,6 +98,11 @@ class MessageCache:
         while len(self._cache) >= self.max_entries:
             self._cache.popitem(last=False)
 
+    def _step(self, span: str, step: str):
+        if self._steps is None:
+            return nullcontext()
+        return timed_span(span, (self._steps, step))
+
     def _hash_batch_device(self, roots: List[bytes]):
         import jax.numpy as jnp
 
@@ -96,33 +111,37 @@ class MessageCache:
         n = len(roots)
         pad = (-n) % BT
         roots_p = list(roots) + [roots[-1]] * pad
-        us = [HC.hash_to_field_fp2(r, 2, HC.DST_G2) for r in roots_p]
-        sgn = np.zeros((2, len(roots_p)), np.int32)
-        for i, (u0, u1) in enumerate(us):
-            sgn[0, i] = HC._sgn0_fp2(u0)
-            sgn[1, i] = HC._sgn0_fp2(u1)
-        enc = lambda vals: jnp.asarray(LY.encode_plain_batch(vals))
-        planes, ok = self._dispatch(
-            "hash_to_g2",
-            IG.hash_to_g2_device,
-            (
+        with self._step("bls.msg.field", "field"):
+            us = [HC.hash_to_field_fp2(r, 2, HC.DST_G2) for r in roots_p]
+            sgn = np.zeros((2, len(roots_p)), np.int32)
+            for i, (u0, u1) in enumerate(us):
+                sgn[0, i] = HC._sgn0_fp2(u0)
+                sgn[1, i] = HC._sgn0_fp2(u1)
+            enc = lambda vals: jnp.asarray(LY.encode_plain_batch(vals))
+            args = (
                 enc([u[0][0] for u in us]),
                 enc([u[0][1] for u in us]),
                 enc([u[1][0] for u in us]),
                 enc([u[1][1] for u in us]),
                 jnp.asarray(sgn),
-            ),
-        )
-        assert bool(np.asarray(ok).all()), "device hash_to_g2 flagged failure"
-        X0, X1, Y0, Y1, Z0, Z1 = (LY.decode_batch(np.asarray(p)) for p in planes)
-        fetched = {}
-        for i, r in enumerate(roots):
-            z = (Z0[i], Z1[i])
-            zi = GT.fp2_inv(z)
-            zi2 = GT.fp2_sqr(zi)
-            x = GT.fp2_mul((X0[i], X1[i]), zi2)
-            y = GT.fp2_mul((Y0[i], Y1[i]), GT.fp2_mul(zi2, zi))
-            fetched[r] = (x, y)
+            )
+        # the host waits here for the hash and for whatever the device
+        # stream holds ahead of it
+        with self._step("bls.msg.device_hash", "device_hash"):
+            planes, ok = self._dispatch("hash_to_g2", IG.hash_to_g2_device, args)
+            ok = np.asarray(ok)
+            planes = [np.asarray(p) for p in planes]
+        assert bool(ok.all()), "device hash_to_g2 flagged failure"
+        with self._step("bls.msg.affine", "affine"):
+            X0, X1, Y0, Y1, Z0, Z1 = (LY.decode_batch(p) for p in planes)
+            fetched = {}
+            for i, r in enumerate(roots):
+                z = (Z0[i], Z1[i])
+                zi = GT.fp2_inv(z)
+                zi2 = GT.fp2_sqr(zi)
+                x = GT.fp2_mul((X0[i], X1[i]), zi2)
+                y = GT.fp2_mul((Y0[i], Y1[i]), GT.fp2_mul(zi2, zi))
+                fetched[r] = (x, y)
         return fetched
 
 

@@ -41,6 +41,7 @@ from ..crypto import curves as C
 from ..kernels import layout as LY
 from ..kernels import verify as KV
 from ..observability import enabled as _trace_enabled
+from ..observability import timed_span as _timed_span
 from ..observability import trace_span as _trace_span
 from ..ops import bls_kernels as BK
 from ..utils.metrics import BlsPoolMetrics
@@ -169,7 +170,9 @@ class TpuBlsVerifier:
         self.max_job_sets = min(max_job_sets, N_BUCKETS[-1])
         # signing-root -> hashed G2 message, device-batched (wire path),
         # dispatched through _device_call like every other device entry
-        self.messages = MessageCache(dispatch=self._device_call)
+        self.messages = MessageCache(
+            dispatch=self._device_call, metrics=self.metrics
+        )
         self._pending_jobs = 0
         # (entry, arg shapes) dispatched before: a new one compiles, and
         # the breaker's watchdog must not count that (compile_window)
@@ -494,7 +497,9 @@ class TpuBlsVerifier:
 
         Everything in here is HOST work (plane encoding, padding,
         dispatch) — it feeds the `lodestar_bls_verify_seconds{phase="host"}`
-        series; `finish_job` owns the device-sync side.
+        series; `finish_job` owns the device-sync side.  Its steps feed
+        `lodestar_bls_job_host_step_seconds{step}`: the message cache's
+        field/device_hash/affine on a miss, then encode and launch.
         """
         t0 = time.perf_counter()
         with _trace_span(
@@ -576,23 +581,34 @@ class TpuBlsVerifier:
             return job
 
         if wire:
-            # SORT by signing root: lane-contiguous message groups let
-            # the batch path run ONE Miller tile per distinct root
-            # (kernels/verify.py grouping rationale) instead of one per
-            # set.  Verdict order is restored through job.unsort.
-            order = sorted(
-                range(len(sets)), key=lambda i: sets[i].signing_root
-            )
-            if order != list(range(len(sets))):
-                sets = [sets[i] for i in order]
-                job.sets = sets
-                job.unsort = np.empty(len(order), np.int64)
-                job.unsort[np.asarray(order)] = np.arange(len(order))
-            job.args, job.valid, n, host_bad = self._prepare_wire(sets)
-            job.decodable = ~host_bad[: len(sets)]
-        else:
-            job.args, job.valid, n = self._prepare(sets)
-            job.decodable = np.array([s.signature is not None for s in sets])
+            # hashed messages first, so that the encode step below holds
+            # none of the message cache's own steps
+            msgs = self.messages.get_many([s.signing_root for s in sets])
+        with self._step("bls.job.encode", "encode"):
+            if wire:
+                # SORT by signing root: lane-contiguous message groups
+                # let the batch path run ONE Miller tile per distinct
+                # root (kernels/verify.py grouping rationale) instead of
+                # one per set.  Verdict order is restored through
+                # job.unsort.
+                order = sorted(
+                    range(len(sets)), key=lambda i: sets[i].signing_root
+                )
+                if order != list(range(len(sets))):
+                    sets = [sets[i] for i in order]
+                    msgs = [msgs[i] for i in order]
+                    job.sets = sets
+                    job.unsort = np.empty(len(order), np.int64)
+                    job.unsort[np.asarray(order)] = np.arange(len(order))
+                job.args, job.valid, n, host_bad = self._prepare_wire(
+                    sets, msgs
+                )
+                job.decodable = ~host_bad[: len(sets)]
+            else:
+                job.args, job.valid, n = self._prepare(sets)
+                job.decodable = np.array(
+                    [s.signature is not None for s in sets]
+                )
         job.n_bucket = n
         if span is not None and _trace_enabled():
             # the (N, K) shape bucket names which compiled pipeline this
@@ -608,23 +624,32 @@ class TpuBlsVerifier:
         if batchable_job:
             # reference: maybeBatch.ts:16 (batch iff >= 2 sets)
             self.metrics.batchable_sigs.inc(len(sets))
-        if batchable_job and self._use_rlc and job.decodable.all():
-            job.batch_ok = self._dispatch_rlc_batch(
-                sets, job.args, job.valid, n, wire
-            )
-        else:
-            if batchable_job and self._use_rlc:
-                # an undecodable signature voids the merged batch: count it
-                # as a batch retry and go straight to per-set verdicts
-                # (with RLC disabled nothing was batched, so no retry)
-                self.metrics.batch_retries.inc()
-                job.batch_retries += 1
-            job.per_set = self._device_call(
-                "each_wire" if job.wire else "each_decoded",
-                self._each_fn(job),
-                (*job.args, job.valid),
-            )
+        # the RLC batch's launch includes drawing its randomizers and
+        # its message-grouping planes
+        with self._step("bls.job.launch", "launch"):
+            if batchable_job and self._use_rlc and job.decodable.all():
+                job.batch_ok = self._dispatch_rlc_batch(
+                    sets, job.args, job.valid, n, wire
+                )
+            else:
+                if batchable_job and self._use_rlc:
+                    # an undecodable signature voids the merged batch:
+                    # count it as a batch retry and go straight to
+                    # per-set verdicts (with RLC disabled nothing was
+                    # batched, so no retry)
+                    self.metrics.batch_retries.inc()
+                    job.batch_retries += 1
+                job.per_set = self._device_call(
+                    "each_wire" if job.wire else "each_decoded",
+                    self._each_fn(job),
+                    (*job.args, job.valid),
+                )
         return job
+
+    def _step(self, span: str, step: str) -> _timed_span:
+        """One host step of `begin_job`: a span when tracing is on, and
+        always an observation of `lodestar_bls_job_host_step_seconds`."""
+        return _timed_span(span, (self.metrics.job_host_step, step))
 
     def _each_fn(self, job):
         return KV.verify_each_device_wire if job.wire else KV.verify_each_device
@@ -665,9 +690,10 @@ class TpuBlsVerifier:
             jnp.asarray(glive),
         )
 
-    def _prepare_wire(self, sets: List[WireSignatureSet]):
-        """Wire sets -> device planes: hashed messages from the device
-        MessageCache, signatures as compressed-x limbs + flag bits."""
+    def _prepare_wire(self, sets: List[WireSignatureSet], msgs=None):
+        """Wire sets -> device planes: hashed messages (`msgs`, aligned
+        with `sets`, or else fetched from the device MessageCache),
+        signatures as compressed-x limbs + flag bits."""
         n = _bucket(len(sets), N_BUCKETS)
         kmax = _bucket(max(len(s.indices) for s in sets), K_BUCKETS)
         idx = np.zeros((n, kmax), np.int32)
@@ -678,7 +704,8 @@ class TpuBlsVerifier:
             idx[i, :k] = s.indices
             kmask[i, :k] = 1
             valid[i] = 1
-        msgs = self.messages.get_many([s.signing_root for s in sets])
+        if msgs is None:
+            msgs = self.messages.get_many([s.signing_root for s in sets])
         g2 = C.G2_GEN
         msgs = msgs + [g2] * (n - len(sets))
         sig_x0, sig_x1, flags, host_bad = encode_wire_planes(

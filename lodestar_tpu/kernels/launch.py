@@ -16,9 +16,6 @@ from typing import Callable, Dict, Tuple
 import jax
 from jax.experimental import pallas as pl
 
-from ..observability import enabled as _trace_enabled
-from ..observability import trace_span as _trace_span
-
 _CACHE: Dict[Tuple, Callable] = {}
 
 # When True, launches lower through the REAL Mosaic path regardless of
@@ -89,14 +86,6 @@ def tiled(kernel, ins, in_rows, out_rows, n: int, bt: int):
             interpret=interp,
         )
         _CACHE[key] = fn
-    if _trace_enabled():
-        # dispatch only — JAX execution is async, so this span measures
-        # trace/lower/launch overhead on the host, not device runtime
-        with _trace_span(
-            "kernels.dispatch", kind="tiled",
-            kernel=getattr(kernel, "__name__", "?"), n=n,
-        ):
-            return fn(*ins)
     return fn(*ins)
 
 

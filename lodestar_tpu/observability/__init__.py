@@ -3,7 +3,10 @@
 Public surface:
 
   - ``trace_span(name, **attrs)`` — context manager AND decorator;
-    near-zero when disabled (``LODESTAR_TPU_TRACE`` unset/0).
+    near-zero when disabled (``LODESTAR_TPU_TRACE`` unset/0); with JAX
+    loaded, an enabled span is also a ``jax.profiler`` annotation.
+  - ``timed_span(name, (histogram, label))`` — a trace_span whose timing
+    also feeds an always-on labelled histogram.
   - ``enabled()`` / ``configure(enabled=, capacity=)`` / ``get_tracer()``
   - ``current_id()`` — explicit parent linking across threads.
   - ``dump_chrome_trace()`` / ``write_chrome_trace(path)`` /
@@ -21,6 +24,7 @@ from .tracer import (  # noqa: F401
     current_id,
     enabled,
     get_tracer,
+    timed_span,
     trace_span,
 )
 from .sinks import (  # noqa: F401
@@ -48,6 +52,7 @@ __all__ = [
     "current_id",
     "enabled",
     "get_tracer",
+    "timed_span",
     "trace_span",
     "dump_chrome_trace",
     "install_registry_sink",

@@ -3,7 +3,7 @@
 The north-star BLS gap was unmeasurable: bench rounds died in opaque
 backend-init probes and tier-1 stalls in cold Mosaic compiles with
 nothing naming where the time went.  This tracer makes the hot paths
-(gossip -> verify -> import, kernel compile/dispatch) emit SPANS —
+(gossip -> verify -> import, kernel compile) emit SPANS —
 named, timestamped, parent-linked intervals — into a bounded
 ring buffer that two sinks consume:
 
@@ -39,10 +39,11 @@ import contextvars
 import functools
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, List, Optional, Tuple
 
 DEFAULT_CAPACITY = 65536
 
@@ -190,9 +191,16 @@ class trace_span:
     When tracing is disabled ``__enter__`` is a flag check; the
     decorator form re-checks per call, so enabling at runtime
     activates already-decorated functions.  ``parent_id`` overrides
-    contextvar parenting for cross-thread links."""
+    contextvar parenting for cross-thread links.
 
-    __slots__ = ("name", "attrs", "parent_id", "_span_id", "_t0", "_token")
+    When tracing is enabled and JAX is already imported, the span also
+    holds a ``jax.profiler.TraceAnnotation`` of the same name, so a
+    profiler capture shows every program span on the thread that ran
+    it, on the device trace's clock."""
+
+    __slots__ = (
+        "name", "attrs", "parent_id", "_span_id", "_t0", "_token", "_ann"
+    )
 
     def __init__(self, name: str, parent_id: Optional[int] = None, **attrs):
         self.name = name
@@ -201,6 +209,7 @@ class trace_span:
         self._span_id = None
         self._t0 = 0
         self._token = None
+        self._ann = None
 
     def set(self, **attrs) -> "trace_span":
         """Attach attributes mid-span (no-op when disabled)."""
@@ -216,14 +225,25 @@ class trace_span:
         if self.parent_id is None:
             self.parent_id = _CURRENT.get()
         self._token = _CURRENT.set(self._span_id)
+        # only a process that already has JAX: importing it here would
+        # pull JAX into processes that never use it
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        span_id = self._span_id
-        if span_id is None:
+        if self._span_id is None:
             return False
-        t1 = time.perf_counter_ns()
+        return self._close(time.perf_counter_ns(), exc_type)
+
+    def _close(self, t1: int, exc_type) -> bool:
+        span_id = self._span_id
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self._token is not None:
             _CURRENT.reset(self._token)
             self._token = None
@@ -256,11 +276,28 @@ class trace_span:
         return wrapper
 
 
-def span_durations_by_name(
-    records: Optional[List[SpanRecord]] = None,
-) -> Dict[str, List[int]]:
-    """name -> [dur_us, ...] over the ring (summary building block)."""
-    out: Dict[str, List[int]] = {}
-    for r in records if records is not None else _STATE.tracer.snapshot():
-        out.setdefault(r.name, []).append(r.dur_us)
-    return out
+class timed_span(trace_span):
+    """``with timed_span("bls.job.encode", (hist, "encode")): ...`` — a
+    trace_span whose one ``perf_counter`` pair also feeds
+    ``hist.observe(label, seconds)``.  The observation is always made,
+    tracing on or off; the span only when tracing is on.  A context
+    manager, not a wrapper: code that traces a kernel keeps its stack."""
+
+    __slots__ = ("_hist", "_label")
+
+    def __init__(self, name: str, observe: Tuple[object, str], **attrs):
+        super().__init__(name, **attrs)
+        self._hist, self._label = observe
+
+    def __enter__(self) -> "timed_span":
+        super().__enter__()
+        if self._span_id is None:
+            self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.perf_counter_ns()
+        self._hist.observe(self._label, (t1 - self._t0) / 1e9)
+        if self._span_id is None:
+            return False
+        return self._close(t1, exc_type)

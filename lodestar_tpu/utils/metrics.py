@@ -392,6 +392,16 @@ class BlsPoolMetrics:
             "phase",
             _SECONDS,
         )
+        # the steps of phase="host", timed where the work happens
+        # (observability.timed_span): field, device_hash and affine in
+        # MessageCache on a miss; encode and launch in begin_job
+        self.job_host_step = r.labeled_histogram(
+            "lodestar_bls_job_host_step_seconds",
+            "Host wall time per verifier job step (field, device_hash, "
+            "affine, encode, launch)",
+            "step",
+            _SECONDS,
+        )
         # accumulate-and-flush pipeline observability (ISSUE 11): how
         # full the shape buckets are when they dispatch, why they
         # dispatched, and how much work is resident end-to-end
