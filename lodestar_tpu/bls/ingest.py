@@ -133,11 +133,13 @@ class MessageCache:
             planes = [np.asarray(p) for p in planes]
         assert bool(ok.all()), "device hash_to_g2 flagged failure"
         with self._step("bls.msg.affine", "affine"):
-            X0, X1, Y0, Y1, Z0, Z1 = (LY.decode_batch(p) for p in planes)
+            # the live lanes only: padding repeats the last root
+            X0, X1, Y0, Y1, Z0, Z1 = (LY.decode_batch(p[:, :n]) for p in planes)
+            # z^-1 = conj(z) / norm(z), every norm inverted in one batch
+            ninv = GT.fp_batch_inv([z0 * z0 + z1 * z1 for z0, z1 in zip(Z0, Z1)])
             fetched = {}
             for i, r in enumerate(roots):
-                z = (Z0[i], Z1[i])
-                zi = GT.fp2_inv(z)
+                zi = (Z0[i] * ninv[i] % P, -Z1[i] * ninv[i] % P)
                 zi2 = GT.fp2_sqr(zi)
                 x = GT.fp2_mul((X0[i], X1[i]), zi2)
                 y = GT.fp2_mul((Y0[i], Y1[i]), GT.fp2_mul(zi2, zi))

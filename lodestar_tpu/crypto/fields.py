@@ -69,6 +69,27 @@ def fp_inv(a: int) -> int:
     return pow(a, P - 2, P)
 
 
+def fp_batch_inv(values) -> list:
+    """[fp_inv(v) for v in values] with one exponentiation for the whole
+    batch (Montgomery's trick): prefix products, one inversion, then a
+    backward pass.  A zero anywhere raises before the product is formed,
+    since it would silently zero every inverse."""
+    vals = [v % P for v in values]
+    if 0 in vals:
+        raise ZeroDivisionError("inverse of 0 in Fp")
+    prefix = []
+    acc = 1
+    for v in vals:
+        prefix.append(acc)
+        acc = acc * v % P
+    inv = pow(acc, P - 2, P) if vals else 1
+    out = [0] * len(vals)
+    for i in range(len(vals) - 1, -1, -1):
+        out[i] = prefix[i] * inv % P
+        inv = inv * vals[i] % P
+    return out
+
+
 def fp_sqrt(a: int):
     """Square root in Fp (p % 4 == 3), or None if a is not a QR."""
     a %= P

@@ -84,10 +84,16 @@ def encode_batch(xs) -> np.ndarray:
     return np.stack([to_limbs(x % P * R_MOD_P % P) for x in xs], axis=-1)
 
 
+_LIMB_WEIGHTS = np.array([1 << (LIMB_BITS * i) for i in range(NL)], dtype=object)
+
+
 def decode_batch(arr) -> list:
-    """Transposed device limbs [NL, B] (lazy form OK) -> plain ints."""
-    a = np.asarray(arr)
-    return [from_limbs(a[:, j]) * R_INV % P for j in range(a.shape[-1])]
+    """Transposed device limbs [NL, B] (lazy form OK) -> plain ints.
+
+    One object-dtype dot over all lanes: exact for signed limbs, as
+    `from_limbs` is."""
+    a = np.asarray(arr).astype(object)
+    return [v * R_INV % P for v in (_LIMB_WEIGHTS[: a.shape[0]] @ a).tolist()]
 
 
 def const_mont(x: int) -> np.ndarray:

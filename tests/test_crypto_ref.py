@@ -84,6 +84,32 @@ class TestFields:
             assert s is not None
             assert F.fp2_eq(F.fp2_sqr(s), sq)
 
+    @pytest.mark.parametrize("n", [0, 1, 2, 130])
+    def test_fp_batch_inv_matches_fp_inv(self, n):
+        # unreduced inputs too: fp_inv reduces them
+        vals = [rand_fp() for _ in range(n - 1)] + [F.P + 7] * (n > 0)
+        assert F.fp_batch_inv(vals) == [F.fp_inv(v) for v in vals]
+
+    @pytest.mark.parametrize("where", [0, 5, 9])
+    def test_fp_batch_inv_raises_on_a_zero_anywhere(self, where):
+        vals = [rand_fp() or 1 for _ in range(10)]
+        vals[where] = F.P  # zero mod p
+        with pytest.raises(ZeroDivisionError):
+            F.fp_batch_inv(vals)
+
+    @pytest.mark.parametrize("n,calls", [(0, 0), (1, 1), (130, 1)])
+    def test_fp_batch_inv_exponentiates_once(self, monkeypatch, n, calls):
+        seen = []
+
+        def counting_pow(*args):
+            seen.append(args)
+            return pow(*args)
+
+        # shadows the builtin inside the module only
+        monkeypatch.setattr(F, "pow", counting_pow, raising=False)
+        F.fp_batch_inv([rand_fp() or 1 for _ in range(n)])
+        assert len(seen) == calls
+
 
 class TestCurves:
     def test_generators_on_curve_and_in_subgroup(self):

@@ -31,14 +31,18 @@ def tracing():
         OB.get_tracer().clear()
 
 
-def _host_hash_dispatch():
+def _host_hash_dispatch(zero_lane=None, widths=None):
     """A stand-in for the device hash: map each lane's u on the host and
     return Jacobian planes (Z != 1, Montgomery limbs) as the kernel
-    does, memoized per distinct u (padding lanes repeat the last root)."""
+    does, memoized per distinct u (padding lanes repeat the last root).
+    `zero_lane` gets Z = 0 (the point at infinity); `widths` collects
+    each batch's lane count."""
     memo = {}
 
     def dispatch(_name, _fn, args):
         u00, u01, u10, u11, _sgn = (np.asarray(a) for a in args)
+        if widths is not None:
+            widths.append(u00.shape[-1])
         cols = [[], [], [], [], [], []]
         for j in range(u00.shape[-1]):
             u = tuple(LY.from_limbs(a[:, j]) for a in (u00, u01, u10, u11))
@@ -46,7 +50,7 @@ def _host_hash_dispatch():
                 q0 = HC.iso3_map(HC.map_to_curve_sswu_g2((u[0], u[1])))
                 q1 = HC.iso3_map(HC.map_to_curve_sswu_g2((u[2], u[3])))
                 x, y = HC.clear_cofactor_g2(C.affine_add(C.FP2_OPS, q0, q1))
-                z = (3 + j, 5)
+                z = (0, 0) if j == zero_lane else (3 + j, 5)
                 z2 = GT.fp2_sqr(z)
                 memo[u] = (
                     *GT.fp2_mul(x, z2), *GT.fp2_mul(y, GT.fp2_mul(z2, z)), *z
@@ -101,6 +105,27 @@ def test_message_cache_spans_are_children_of_the_enclosing_span(tracing):
     # the span and the histogram come from one timing
     for name, step in zip(names, MSG_STEPS):
         assert abs(metrics.job_host_step.sum(step) * 1e6 - recs[name].dur_us) <= 1
+
+
+@pytest.mark.parametrize("n,width", [(1, 128), (130, 256)])
+def test_affine_step_returns_the_host_hash_exactly(n, width):
+    roots = [i.to_bytes(4, "big") * 8 for i in range(n)]
+    widths = []
+    cache = MessageCache(dispatch=_host_hash_dispatch(widths=widths))
+    assert cache.get_many(roots) == [HC.hash_to_g2(r) for r in roots]
+    assert widths == [width]
+
+
+@pytest.mark.parametrize("lane", [0, 1])
+def test_affine_step_raises_on_a_zero_z_and_caches_nothing(lane):
+    cache = MessageCache(dispatch=_host_hash_dispatch(zero_lane=lane))
+    with pytest.raises(ZeroDivisionError):
+        cache.get_many(ROOTS)
+    assert len(cache._cache) == 0
+    # a healthy batch after it is answered and cached
+    cache._dispatch = _host_hash_dispatch()
+    assert cache.get_many(ROOTS) == [HC.hash_to_g2(r) for r in ROOTS]
+    assert len(cache._cache) == 2
 
 
 STEPS = MSG_STEPS + ("encode", "launch")

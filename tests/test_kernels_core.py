@@ -44,6 +44,19 @@ def test_codec_roundtrip():
     assert dec(enc(xs)) == [x % P for x in xs]
 
 
+@pytest.mark.parametrize("width", [1, 128, 256])
+def test_decode_batch_matches_per_lane_decode(width):
+    # signed lazy-form limbs at the L- and T-bounds
+    rng = np.random.default_rng(width)
+    a = rng.integers(-4103, 4104, size=(LY.NL, width)).astype(np.int32)
+    a[-1] = rng.integers(-300, 301, size=width)
+    a[:-1, 0], a[-1, 0] = -4103, -300  # the most negative value
+    want = [LY.from_limbs(a[:, j]) * LY.R_INV % P for j in range(width)]
+    assert LY.decode_batch(a) == want
+    assert LY.decode_batch(jnp.asarray(a)) == want
+    assert all(type(v) is int for v in LY.decode_batch(a))
+
+
 def test_fold_preserves_value():
     rng = np.random.default_rng(1)
     t = rng.integers(-(1 << 29), 1 << 29, size=(LY.NC, B)).astype(np.int32)
