@@ -190,16 +190,39 @@ def _stages():
 
 
 def _programs():
-    from lodestar_tpu.kernels import rlc_entries as RE
+    """The whole programs the verifier dispatches, at the registry size:
+    (fn, specs) in bls/verifier's argument order."""
+    from lodestar_tpu.kernels import verify as KV
 
-    t = TABLE_ROWS
+    nl, t = KV.NL, TABLE_ROWS
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def wire(n, k):  # table planes, index/mask, message planes, signature
+        return [sds(nl, t)] * 2 + [sds(n, k)] * 2 + [sds(nl, n)] * 6 + [sds(2, n)]
+
+    def rand_valid(n):
+        return [sds(KV.RAND_WORDS, n), sds(n)]
+
+    def grouped(n):
+        return (
+            KV.verify_batch_device_wire_grouped,
+            wire(n, 1) + [sds(n), sds(KV.BT), sds(KV.BT)] + rand_valid(n),
+        )
+
     return {
-        "grouped512": RE.export_specs_batch_wire_grouped(n=512, k=1, table=t),
-        "grouped128": RE.export_specs_batch_wire_grouped(n=128, k=1, table=t),
-        "batch128_k64": RE.export_specs_batch_wire(n=128, k=64, table=t),
-        "each128": RE.export_specs_each_wire(n=128, k=1, table=t),
-        "each128_k64": RE.export_specs_each_wire(n=128, k=64, table=t),
-        "agg128": RE.export_specs_agg_g2_sum(n=128),
+        "grouped512": grouped(512),
+        "grouped128": grouped(128),
+        "batch128_k64": (
+            KV.verify_batch_device_wire, wire(128, 64) + rand_valid(128)
+        ),
+        "each128": (KV.verify_each_device_wire, wire(128, 1) + [sds(128)]),
+        "each128_k64": (KV.verify_each_device_wire, wire(128, 64) + [sds(128)]),
+        "agg128": (
+            KV.aggregate_g2_sum_device,
+            [sds(nl, 128)] * 2 + [sds(2, 128), sds(128), sds(KV.BT), sds(KV.BT)],
+        ),
     }
 
 

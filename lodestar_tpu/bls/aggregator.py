@@ -32,8 +32,8 @@ sitting AHEAD of the pipeline's accumulators:
     gathered ONCE per layer (ISSUE 13 satellite).
   - **One set per layer.**  A layer's signatures are point-added in G2
     — on device through `verifier.aggregate_wire_signatures` (the
-    `agg_g2_sum` export-cache entry wrapping kernels/verify.py's
-    segmented jacobian sum) with a host ground-truth fallback — and the
+    `agg_g2_sum` dispatch of kernels/verify.py's segmented jacobian
+    sum) with a host ground-truth fallback — and the
     layer verifies as ONE `WireSignatureSet.aggregate` through the
     existing RLC batch path, K-bucketed and message-grouped like any
     other set.  Both legs sit under the device circuit breaker

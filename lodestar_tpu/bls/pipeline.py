@@ -12,8 +12,8 @@ so the pipeline accumulates:
 
   - **Shape buckets.**  Batchable submissions coalesce ACROSS gossip
     topics/subnets into per-(kind, K-bucket, lane) accumulators — the
-    exact shape classes the export cache holds artifacts for
-    (`kernels/export_cache.py`, `kernels/rlc_entries.py`).  A bucket
+    exact shape classes the verifier's stage programs are built for
+    (each saved to the export cache, `kernels/launch.py`).  A bucket
     that exactly fills an N-bucket (verifier.N_BUCKETS) flushes
     IMMEDIATELY: waiting longer can only burn deadline latency or spill
     into the next, twice-as-large bucket.

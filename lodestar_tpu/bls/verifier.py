@@ -352,8 +352,8 @@ class TpuBlsVerifier:
         undecodable — the caller then dispatches the members
         unaggregated).  This is the aggregation stage's sum seam
         (bls/aggregator.py): on the TPU backend the adds run in one
-        batched device dispatch (kernels/verify.aggregate_g2_sum_device
-        via the `agg_g2_sum` export-cache entry); elsewhere — and as
+        batched device dispatch (kernels/verify.aggregate_g2_sum_device,
+        dispatched as `agg_g2_sum`); elsewhere — and as
         the fault fallback — the host ground-truth path decompresses
         and jacobian-adds per group."""
         groups = [list(g) for g in groups]

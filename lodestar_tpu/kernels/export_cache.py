@@ -1,24 +1,33 @@
 """AOT export cache — trace once, reload without re-tracing.
 
-The wire verify pipeline unrolls 33-limb schoolbook arithmetic into a
-large jaxpr; TRACING it costs minutes of host time per process, while
-the XLA/Mosaic compile can be served by the persistent compile cache.
-Tracing is pure Python work over static shapes, so it can be paid
-ONCE, the result serialized with `jax.export`, and every later process
-deserializes in milliseconds and goes straight to (cached) compilation.
-The BLS verifier does not dispatch through it (PR 21): an artifact is
-one monolithic program per (entry, shape), so a COLD start compiles
-every stage kernel again for each shape, where direct dispatch
-compiles each stage once per process (PERF.md).
+The verify pipeline unrolls 33-limb schoolbook arithmetic into large
+kernel bodies; TRACING and LOWERING them cost minutes of host time per
+process, while the XLA/Mosaic compile is served by the persistent
+compile cache.  Tracing is pure Python work over static shapes, so it
+is paid ONCE, the result serialized with `jax.export`, and every later
+process deserializes it and goes straight to (cached) compilation.
+
+`load_or_export` is the one path in and out of the cache.  The BLS
+verifier's artifacts are per stage: kernels/launch.py hands every
+pallas launch it builds to it, one artifact per (kernel, operand
+shapes, grid) signature, exported for the platform the process runs
+on.  Each stage stays its own program, so a stage shared by several
+entry points and buckets still compiles once.  Whole programs use the
+same path (the slasher's span update, the HTR merkleization kernels,
+the standalone registry below).
 
 Artifacts are keyed by (entry name, shape/dtype signature, platform,
-jax version, kernels-code fingerprint); a stale fingerprint falls back
-to a fresh trace, so a kernel edit can never run an outdated artifact.
+jax version, code fingerprint, declared sources, detail); a stale key
+or an unreadable file falls back to a fresh trace, so an edit can
+never run an outdated artifact, and a cache that cannot be read or
+written only costs the trace.  They live in `default_dir()`: beside
+the compile cache, so a run that finds its compiles warm finds its
+exports.
 
-Cross-platform: `platform="tpu"` artifacts are traced on this CPU host
-with the real Mosaic lowering forced (launch.force_mosaic) — export
-runs jax lowering only; the Mosaic->TPU-binary compile still happens
-on-device at first call, hitting the persistent compile cache.
+Cross-platform: `platform="tpu"` whole-program artifacts are traced on
+a CPU host with the real Mosaic lowering forced (launch.force_mosaic) —
+export runs jax lowering only; the Mosaic->TPU-binary compile still
+happens on-device at first call, hitting the persistent compile cache.
 """
 
 from __future__ import annotations
@@ -26,16 +35,24 @@ from __future__ import annotations
 import hashlib
 import os
 import pathlib
+import time
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 
-# a fixed directory inside the checkout (gitignored), so a later
-# process on the same checkout finds what an earlier one wrote
-DEFAULT_DIR = os.environ.get(
-    "LODESTAR_TPU_EXPORT_CACHE",
-    str(pathlib.Path(__file__).resolve().parents[2] / ".export_cache"),
-)
+
+def default_dir() -> str:
+    """`LODESTAR_TPU_EXPORT_CACHE` when set, else the `exports`
+    subdirectory of the compile cache (utils/compile_cache.cache_dir():
+    `JAX_COMPILATION_CACHE_DIR` or `<checkout>/.jax_cache`, gitignored),
+    so a later process finds what an earlier one wrote wherever it
+    finds its compiles."""
+    from ..utils.compile_cache import cache_dir
+
+    return os.environ.get("LODESTAR_TPU_EXPORT_CACHE") or os.path.join(
+        cache_dir(), "exports"
+    )
+
 
 # in-process cache of deserialized/exported entries
 _LOADED: Dict[str, object] = {}
@@ -84,9 +101,10 @@ def metrics() -> _Metrics:
     return _METRICS
 
 
-# Kernel sources OUTSIDE kernels/ whose traced computations live in the
-# cache, keyed per entry NAME (standalone registry entries declare
-# theirs at registration).  They fold into THAT entry's artifact key
+# Kernel sources OUTSIDE kernels/ and crypto/ whose traced computations
+# live in the cache, keyed per entry NAME (declare_sources: standalone
+# registry entries declare theirs at registration, launched stages
+# whose kernel lives elsewhere at launch).  They fold into THAT entry's artifact key
 # only — an edit to slasher/device.py must invalidate the span-update
 # artifact without staling every verify-pipeline artifact on the host.
 # Values are tuples of dotted module names (preferred: statically
@@ -112,15 +130,23 @@ def _source_path(src: str) -> Optional[pathlib.Path]:
     return None
 
 
-def _code_fingerprint() -> str:
-    """Hash of every kernels/*.py source file: a kernel edit invalidates
-    all artifacts (they embed the traced computation)."""
+# packages whose sources every artifact embeds: the kernels, and the
+# crypto modules whose constants (Montgomery-encoded curve and
+# hash-to-curve constants) the kernels bake in
+_FINGERPRINT_PACKAGES = ("kernels", "crypto")
+
+
+def _code_fingerprint(root: Optional[pathlib.Path] = None) -> str:
+    """Hash of every kernels/*.py and crypto/*.py source file under
+    `root` (the package): an edit there invalidates all artifacts
+    (they embed the traced computation and its constants)."""
+    root = root or pathlib.Path(__file__).parent.parent
     h = hashlib.sha256()
-    pkg = pathlib.Path(__file__).parent
-    for p in sorted(pkg.glob("*.py")):
-        if p.name == "export_cache.py":
-            continue  # this module does not affect traced computations
-        h.update(p.read_bytes())
+    for pkg in _FINGERPRINT_PACKAGES:
+        for p in sorted((root / pkg).glob("*.py")):
+            if p.name == "export_cache.py":
+                continue  # this module does not affect traced computations
+            h.update(p.read_bytes())
     return h.hexdigest()[:16]
 
 
@@ -135,10 +161,17 @@ def code_fingerprint() -> str:
 
 
 def artifact_key(
-    name: str, specs: Sequence[jax.ShapeDtypeStruct], platform: str
+    name: str,
+    specs: Sequence[jax.ShapeDtypeStruct],
+    platform: str,
+    detail: str = "",
 ) -> str:
+    """The artifact's file stem.  `detail` folds in what the name and
+    shapes leave out (a stage's grid and block rows, its sources)."""
     sig = ";".join(f"{tuple(s.shape)}:{s.dtype}" for s in specs)
     raw = f"{name}|{sig}|{platform}|{jax.__version__}|{code_fingerprint()}"
+    if detail:
+        raw += "|" + detail
     for source in sorted(_ENTRY_SOURCES.get(name, ())):
         path = _source_path(source)
         if path is not None and path.exists():
@@ -153,9 +186,44 @@ def artifact_key(
 
 
 def _path(key: str, cache_dir: Optional[str]) -> pathlib.Path:
-    d = pathlib.Path(cache_dir or DEFAULT_DIR)
-    d.mkdir(parents=True, exist_ok=True)
-    return d / f"{key}.jaxexport"
+    return pathlib.Path(cache_dir or default_dir()) / f"{key}.jaxexport"
+
+
+def _read(name: str, key: str, platform: str, cache_dir: Optional[str]):
+    """The deserialized artifact at `key`, or None when it is absent or
+    unreadable (no cache directory, truncated, corrupt: the caller
+    traces afresh)."""
+    from jax import export as jexport
+
+    from ..observability import trace_span
+
+    t0 = time.perf_counter()
+    with trace_span("kernels.export_load", entry=name, platform=platform):
+        try:
+            exp = jexport.deserialize(_path(key, cache_dir).read_bytes())
+        except Exception:  # noqa: BLE001 — absent/stale/corrupt: re-trace
+            return None
+    metrics().load_seconds.observe(name, time.perf_counter() - t0)
+    metrics().hits.inc(name, 1.0)
+    return exp
+
+
+def _write(name: str, path: pathlib.Path, blob: bytes) -> None:
+    """Write through a temporary name and rename, so a process reading
+    the cache never sees half an artifact.  A cache that cannot be
+    written is a warning: the program runs, and later processes trace
+    it again."""
+    tmp = path.with_name(f"{path.name}.{os.getpid()}.tmp")
+    try:
+        path.parent.mkdir(parents=True, exist_ok=True)
+        tmp.write_bytes(blob)
+        os.replace(tmp, path)
+    except OSError as e:
+        from ..utils.logger import get_logger
+
+        get_logger("kernels/export_cache").warn(
+            f"export cache write failed for {name!r}: {e}"
+        )
 
 
 def load(
@@ -163,30 +231,17 @@ def load(
     specs: Sequence[jax.ShapeDtypeStruct],
     platform: str,
     cache_dir: Optional[str] = None,
+    detail: str = "",
 ) -> Optional[Callable]:
     """Deserialize a cached artifact; None when absent/stale."""
-    from jax import export as jexport
-
-    import time
-
-    key = artifact_key(name, specs, platform)
+    key = artifact_key(name, specs, platform, detail)
     hit = _LOADED.get(key)
     if hit is not None:
         metrics().hits.inc(name, 1.0)
         return hit.call
-    path = _path(key, cache_dir)
-    if not path.exists():
+    exp = _read(name, key, platform, cache_dir)
+    if exp is None:
         return None
-    from ..observability import trace_span
-
-    t0 = time.perf_counter()
-    with trace_span("kernels.export_load", entry=name, platform=platform):
-        try:
-            exp = jexport.deserialize(path.read_bytes())
-        except Exception:  # stale/corrupt artifact: re-trace
-            return None
-    metrics().load_seconds.observe(name, time.perf_counter() - t0)
-    metrics().hits.inc(name, 1.0)
     _LOADED[key] = exp
     return exp.call
 
@@ -197,19 +252,18 @@ def export_and_save(
     specs: Sequence[jax.ShapeDtypeStruct],
     platform: str,
     cache_dir: Optional[str] = None,
+    detail: str = "",
 ) -> Callable:
     """Trace `fn` for `platform` at `specs`, persist, return the call.
 
     For platform="tpu" on a CPU host the pallas launches are forced
     through the real Mosaic lowering (launch.force_mosaic)."""
-    import time
-
     from jax import export as jexport
 
     from ..observability import trace_span
     from . import launch
 
-    key = artifact_key(name, specs, platform)
+    key = artifact_key(name, specs, platform, detail)
     jitted = jax.jit(fn)
     t0 = time.perf_counter()
     with trace_span("kernels.export_trace", entry=name, platform=platform):
@@ -218,7 +272,7 @@ def export_and_save(
                 exp = jexport.export(jitted, platforms=[platform])(*specs)
         else:
             exp = jexport.export(jitted, platforms=[platform])(*specs)
-        _path(key, cache_dir).write_bytes(exp.serialize())
+        _write(name, _path(key, cache_dir), exp.serialize())
     metrics().trace_seconds.observe(name, time.perf_counter() - t0)
     _LOADED[key] = exp
     return exp.call
@@ -246,12 +300,15 @@ def load_or_export(
     specs: Sequence[jax.ShapeDtypeStruct],
     platform: Optional[str] = None,
     cache_dir: Optional[str] = None,
+    detail: str = "",
 ) -> Callable:
     """The main entry: cached call if present, else trace+persist.
-    Stage faults re-raise as ExportStageError (classification seam)."""
+    `detail` folds into the key what the name and shapes leave out (a
+    launched stage's kernel, grid and block rows).  Stage faults
+    re-raise as ExportStageError (classification seam)."""
     platform = platform or jax.default_backend()
     try:
-        cached = load(name, specs, platform, cache_dir)
+        cached = load(name, specs, platform, cache_dir, detail)
     except Exception as e:  # noqa: BLE001 — load() already swallows
         # corrupt artifacts; anything else here is the backend dying
         raise ExportStageError("load", name, e) from e
@@ -259,7 +316,7 @@ def load_or_export(
         return cached
     metrics().misses.inc(name, 1.0)
     try:
-        return export_and_save(name, fn, specs, platform, cache_dir)
+        return export_and_save(name, fn, specs, platform, cache_dir, detail)
     except Exception as e:  # noqa: BLE001 — trace/persist faults carry
         # their stage for the breaker's outcome classification
         raise ExportStageError("trace", name, e) from e
@@ -267,10 +324,10 @@ def load_or_export(
 
 # -- standalone entry registry ----------------------------------------------
 #
-# Entries that don't flow through the verify pipeline's dispatch capture
-# (dev/export_pipeline.py) register a spec builder here so offline
-# pre-tracing covers them too.  A builder returns (fn, specs); it is
-# invoked lazily — registration itself must stay import-cheap.
+# Whole-program entries register a spec builder here so offline
+# pre-tracing (dev/export_pipeline.py) covers them.  A builder returns
+# (fn, specs); it is invoked lazily — registration itself must stay
+# import-cheap.
 
 _ENTRY_BUILDERS: Dict[str, Callable] = {}
 
@@ -304,6 +361,19 @@ def _check_entry_sources(name: str, fn: Callable) -> None:
     )
 
 
+def declare_sources(name: str, sources: Sequence[str]) -> None:
+    """Declare the modules (dotted names or file paths) OUTSIDE the
+    fingerprinted packages whose code entry `name` traces: they fold
+    into that entry's artifact key only.  An empty declaration clears
+    an earlier one (it would fold unrelated hashes into the key and
+    pacify the runtime backstop)."""
+    # tpulint: disable=kernel-purity -- host-side registry of module names; launches call it while tracing, never on traced values
+    if sources:
+        _ENTRY_SOURCES[name] = tuple(sources)
+    else:
+        _ENTRY_SOURCES.pop(name, None)
+
+
 def register_entry(
     name: str,
     builder: Callable,
@@ -320,13 +390,7 @@ def register_entry(
         declared.append(source)
     if sources is not None:
         declared.extend(sources)
-    if declared:
-        _ENTRY_SOURCES[name] = tuple(declared)
-    else:
-        # re-registration without sources must not inherit a stale
-        # declaration (it would fold unrelated hashes into the key and
-        # pacify the runtime backstop)
-        _ENTRY_SOURCES.pop(name, None)
+    declare_sources(name, declared)
 
     def checked_builder():
         fn, specs = builder()
@@ -412,16 +476,9 @@ def export_registered(platform: str, cache_dir: Optional[str] = None) -> Dict[st
     return out
 
 
-# the RLC verify entries' pre-trace buckets: the default service batch
-# (rlc_entries.DEF_N — kept literal here so registration stays
-# import-cheap) and the bench/replay batch
-_RLC_BUCKETS = (128, 512)
-
-
 def _register_builtin_entries() -> None:
     """Register the subsystem kernels that live outside kernels/ (the
-    slasher's whole-window span update), the RLC verification entry
-    points (kernels/rlc_entries.py spec builders), and the HTR device
+    slasher's whole-window span update) and the HTR device
     merkleization kernels (kernels/sha256.py spec builders)."""
 
     def _slasher_span():
@@ -436,88 +493,6 @@ def _register_builtin_entries() -> None:
             "lodestar_tpu.slasher.device",
             "lodestar_tpu.slasher.batch",
         ),
-    )
-
-    # The RLC verify pipeline's device entries, under the SAME names
-    # bls/verifier._device_call dispatches with — registration makes
-    # export_registered() pre-trace them at BOTH service buckets
-    # (_RLC_BUCKETS) AND folds the crypto constant modules
-    # (Montgomery-encoded curve constants bake into the traced kernels)
-    # into every artifact key for these names, wire- and decoded-path
-    # alike.  Builders spell out literal names + direct function
-    # returns so tpulint's fingerprint-completeness rule can chase them
-    # statically.
-    def _rlc_batch_wire(bucket: int):
-        from .rlc_entries import export_specs_batch_wire
-
-        return export_specs_batch_wire(n=bucket)
-
-    def _rlc_batch_wire_grouped(bucket: int):
-        from .rlc_entries import export_specs_batch_wire_grouped
-
-        return export_specs_batch_wire_grouped(n=bucket)
-
-    def _rlc_each_wire(bucket: int):
-        from .rlc_entries import export_specs_each_wire
-
-        return export_specs_each_wire(n=bucket)
-
-    def _rlc_batch_decoded(bucket: int):
-        from .rlc_entries import export_specs_batch_decoded
-
-        return export_specs_batch_decoded(n=bucket)
-
-    def _rlc_each_decoded(bucket: int):
-        from .rlc_entries import export_specs_each_decoded
-
-        return export_specs_each_decoded(n=bucket)
-
-    # sources spelled as per-call string-literal tuples: the tpulint
-    # fingerprint rule only accepts statically-readable declarations
-    bucketed_entry(
-        "batch_wire",
-        _rlc_batch_wire,
-        buckets=_RLC_BUCKETS,
-        sources=("lodestar_tpu.crypto.curves", "lodestar_tpu.crypto.fields"),
-    )
-    bucketed_entry(
-        "batch_wire_grouped",
-        _rlc_batch_wire_grouped,
-        buckets=_RLC_BUCKETS,
-        sources=("lodestar_tpu.crypto.curves", "lodestar_tpu.crypto.fields"),
-    )
-    bucketed_entry(
-        "each_wire",
-        _rlc_each_wire,
-        buckets=_RLC_BUCKETS,
-        sources=("lodestar_tpu.crypto.curves", "lodestar_tpu.crypto.fields"),
-    )
-    bucketed_entry(
-        "batch_decoded",
-        _rlc_batch_decoded,
-        buckets=_RLC_BUCKETS,
-        sources=("lodestar_tpu.crypto.curves", "lodestar_tpu.crypto.fields"),
-    )
-    bucketed_entry(
-        "each_decoded",
-        _rlc_each_decoded,
-        buckets=_RLC_BUCKETS,
-        sources=("lodestar_tpu.crypto.curves", "lodestar_tpu.crypto.fields"),
-    )
-
-    # the pre-verify aggregation stage's batched G2-sum (ISSUE 13):
-    # same crypto-constant fingerprint scope as the verify entries (the
-    # decompression + group-law kernels bake the same curve constants)
-    def _agg_g2_sum(bucket: int):
-        from .rlc_entries import export_specs_agg_g2_sum
-
-        return export_specs_agg_g2_sum(n=bucket)
-
-    bucketed_entry(
-        "agg_g2_sum",
-        _agg_g2_sum,
-        buckets=_RLC_BUCKETS,
-        sources=("lodestar_tpu.crypto.curves", "lodestar_tpu.crypto.fields"),
     )
 
     # The HTR device-merkleization kernels (ISSUE 16): hash-pairs at

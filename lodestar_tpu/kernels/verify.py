@@ -25,7 +25,9 @@ grid is its own compiled program):
 
 The verifier dispatches these stages un-jitted: each pallas stage and
 each glue jit is its own program, compiled once per process and shared
-by every entry point and K-bucket that uses it.
+by every entry point and K-bucket that uses it.  Each pallas stage is
+also exported once and loaded by later processes without a trace
+(kernels/launch.py).
 """
 
 from __future__ import annotations
@@ -92,9 +94,9 @@ def _tiled(kernel, ins, in_rows, out_rows, n):
 # work and ONE final exponentiation; the per-set retry path pays 2N
 # Miller lanes and N final exps.  Counts are derived from static shapes
 # at dispatch time, so they tick on the DIRECT call path (tests, CPU
-# backend, microbenches).  Under the AOT export cache the pipeline body
-# runs once at trace time only — tally deltas there describe one traced
-# job, not live traffic (use the launch.py dispatch spans for that).
+# backend, microbenches).  Inside a whole-program export
+# (export_cache.load_or_export) the pipeline body runs once at trace
+# time only — tally deltas there describe one traced job, not traffic.
 
 from collections import Counter as _Counter
 
@@ -429,7 +431,7 @@ def _gather_pk(table_x, table_y, idx, kmask):
     gy = jnp.moveaxis(gy, 2, 0)
     m = jnp.moveaxis(kmask, 1, 0)  # [K, N]
     kc = min(k, 32)
-    fn = LA.cached(
+    ox, oy, oz, oinf = LA.cached(
         ("agg_pk", n, k, kc),
         lambda: pl.pallas_call(
             _k_agg_pk,
@@ -444,8 +446,8 @@ def _gather_pk(table_x, table_y, idx, kmask):
             + [pl.BlockSpec((1, BT), lambda i, k_: (0, i))],
             interpret=LA.interpret(),
         ),
+        gx, gy, m,
     )
-    ox, oy, oz, oinf = fn(gx, gy, m)
     return (ox, oy, oz), (oinf[0] != 0)
 
 
@@ -687,7 +689,7 @@ def _batch_core(
 
 def _sum_g2(x0, x1, y0, y1, z0, z1, excl, n):
     """Lane-tiled grid accumulation wrapper for _k_sum_g2 (full width)."""
-    fn = LA.cached(
+    return LA.cached(
         ("sum_g2", n),
         lambda: pl.pallas_call(
             _k_sum_g2,
@@ -699,13 +701,13 @@ def _sum_g2(x0, x1, y0, y1, z0, z1, excl, n):
             + [pl.BlockSpec((1, BT), lambda i: (0, 0))],
             interpret=LA.interpret(),
         ),
+        x0, x1, y0, y1, z0, z1, excl,
     )
-    return fn(x0, x1, y0, y1, z0, z1, excl)
 
 
 def _prod(fN, live_i, n):
     """Lane-tiled grid accumulation wrapper for _k_prod (full width)."""
-    fn = LA.cached(
+    return LA.cached(
         ("prod", n),
         lambda: pl.pallas_call(
             _k_prod,
@@ -716,8 +718,8 @@ def _prod(fN, live_i, n):
             out_specs=[pl.BlockSpec((NL, BT), lambda i: (0, 0))] * 12,
             interpret=LA.interpret(),
         ),
+        live_i, *fN,
     )
-    return fn(live_i, *fN)
 
 
 # ---------------------------------------------------------------------------
@@ -748,7 +750,7 @@ def _seg_scan(kernel, planes, n_out, ins, n):
     bt = min(n, BT)
     tile = lambda r: pl.BlockSpec((r, bt), lambda i: (0, i))  # noqa: E731
     held = lambda r: pl.BlockSpec((r, bt), lambda i: (0, 0))  # noqa: E731
-    fn = LA.cached(
+    return LA.cached(
         ("seg_scan", kernel, n),
         lambda: pl.pallas_call(
             kernel,
@@ -760,8 +762,8 @@ def _seg_scan(kernel, planes, n_out, ins, n):
             + [held(NL)] * n_out + [held(1)] * 2,
             interpret=LA.interpret(),
         ),
-    )
-    return fn(*ins)[: n_out + 1]
+        *ins,
+    )[: n_out + 1]
 
 
 def _seg_sum_g1(px, py, pz, dead, group):

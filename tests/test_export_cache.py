@@ -128,7 +128,8 @@ def test_cross_platform_tpu_export_from_cpu_host(tmp_path):
                 out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
                 interpret=launch.interpret(),
             ),
-        )(x)
+            x,
+        )
 
     x = jnp.zeros((8, 128), jnp.int32)
     specs = [jax.ShapeDtypeStruct(x.shape, x.dtype)]
@@ -142,47 +143,6 @@ def test_cross_platform_tpu_export_from_cpu_host(tmp_path):
     # TPU — reload only
     EC._LOADED.clear()
     assert EC.load("mosaic-x", specs, "tpu", cache_dir=str(tmp_path)) is not None
-
-
-def test_staged_artifacts_match_verifier_contract():
-    """When the staged TPU artifacts exist (driver host), their input
-    signature must match what the verifier dispatches at bench shapes —
-    a drift between verifier args and artifacts would silently fall
-    back to the ~10-minute trace at bench time.  Skips on hosts
-    without the artifact cache (fresh checkouts)."""
-    import pathlib
-
-    from jax import export as jexport
-
-    hits = list(
-        pathlib.Path(EC.DEFAULT_DIR).glob("batch_wire_grouped-tpu-*.jaxexport")
-    )
-    if not hits:
-        pytest.skip("no staged artifacts on this host")
-    from lodestar_tpu.kernels import verify as KV
-
-    for path in hits:  # one artifact per (job width x table capacity)
-        exp = jexport.deserialize(path.read_bytes())
-        avals = list(exp.in_avals)
-        # 16 positional args; lane width divides the tile; grouping
-        # rows are BT-wide (verify_batch_device_wire_grouped); the
-        # TABLE planes carry the capacity (bench 512, replay 500k/1M)
-        assert len(avals) == 16, path.name
-        n = avals[-1].shape[0]
-        if avals[14].shape[0] != KV.RAND_WORDS:
-            # a pre-128-bit-randomizer artifact left on disk: its
-            # fingerprint key is stale so it can never LOAD — only the
-            # current-generation contract is asserted
-            continue
-        assert n % KV.BT == 0
-        assert avals[0].shape[0] == KV.NL    # table planes [NL, cap]
-        assert avals[1].shape == avals[0].shape
-        assert avals[4].shape == (KV.NL, n)  # msg planes ride the job
-        assert avals[11].shape == (n,)       # group
-        assert avals[12].shape == (KV.BT,)   # head_lanes
-        assert avals[13].shape == (KV.BT,)   # glive
-        assert avals[14].shape == (KV.RAND_WORDS, n)  # rwords
-        assert all(str(a.dtype) == "int32" for a in avals)
 
 
 # ---------------------------------------------------------------------------
@@ -256,36 +216,6 @@ def test_builtin_slasher_entry_declares_its_import_graph(caplog):
     assert not any(
         "slasher_span_update" in r.message for r in caplog.records
     )
-
-
-def test_builtin_rlc_entries_cover_every_dispatch_name():
-    """Every device entry name bls/verifier._device_call dispatches must
-    be a REGISTERED entry (pre-traceable offline) declaring the crypto
-    constant modules its trace bakes in — so a curve-constant edit
-    invalidates the artifacts and export_registered() covers the RLC
-    pipeline without replaying the bench world."""
-    from lodestar_tpu.kernels import verify as KV
-
-    names = (
-        "batch_wire", "batch_wire_grouped", "each_wire",
-        "batch_decoded", "each_decoded",
-    )
-    registered = EC.registered_entries()
-    for name in names:
-        assert name in registered, name
-        declared = EC._ENTRY_SOURCES[name]
-        assert "lodestar_tpu.crypto.curves" in declared, name
-        assert "lodestar_tpu.crypto.fields" in declared, name
-        for src in declared:
-            p = EC._source_path(src)
-            assert p is not None and p.exists(), (name, src)
-        fn, specs = registered[name]()
-        # the traced fn is the verifier's dispatch target and the specs
-        # carry the 128-bit randomizer rows on batch entries
-        assert fn.__module__ == "lodestar_tpu.kernels.verify", name
-        if name.startswith("batch"):
-            assert tuple(specs[-2].shape)[0] == KV.RAND_WORDS, name
-        assert all(str(s.dtype) == "int32" for s in specs), name
 
 
 def test_artifact_key_tracks_every_declared_source(tmp_path):
