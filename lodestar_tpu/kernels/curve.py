@@ -345,6 +345,22 @@ def jac_add_mixed_or_full(fo: FieldOps, p, q):
     return (X3, Y3, Z3)
 
 
+def static_bit_reader(k: int):
+    """bit(pos) of a static scalar 0 <= k < 2^64 at a traced int32 bit
+    position, read from two uint32 words by shifts and a select: no
+    dynamic array indexing, so it lowers in Mosaic."""
+    hi = jnp.uint32((k >> 32) & 0xFFFFFFFF)
+    lo = jnp.uint32(k & 0xFFFFFFFF)
+
+    def bit(pos):
+        p = pos.astype(jnp.uint32)
+        b_hi = (hi >> (p - jnp.uint32(32))) & jnp.uint32(1)
+        b_lo = (lo >> p) & jnp.uint32(1)
+        return jnp.where(pos >= 32, b_hi, b_lo)
+
+    return bit
+
+
 def scalar_mul_static(fo: FieldOps, q_aff, k: int):
     """k*Q for a STATIC positive scalar (< 2^64), Q affine and not O.
 
@@ -357,16 +373,11 @@ def scalar_mul_static(fo: FieldOps, q_aff, k: int):
     one = _one_plane_like(fo, q_aff[0])
     T = (q_aff[0], q_aff[1], one)
     nbits = k.bit_length() - 1
-    hi = jnp.uint32((k >> 32) & 0xFFFFFFFF)
-    lo = jnp.uint32(k & 0xFFFFFFFF)
+    bit_at = static_bit_reader(k)
 
     def body(i, T):
         T = jac_dbl(fo, T)
-        pos = jnp.int32(nbits - 1) - i
-        p = pos.astype(jnp.uint32)
-        b_hi = (hi >> (p - jnp.uint32(32))) & jnp.uint32(1)
-        b_lo = (lo >> p) & jnp.uint32(1)
-        bit = jnp.where(pos >= 32, b_hi, b_lo)
+        bit = bit_at(jnp.int32(nbits - 1) - i)
         return lax.cond(
             bit != 0, lambda t: jac_add_mixed(fo, t, q_aff), lambda t: t, T
         )

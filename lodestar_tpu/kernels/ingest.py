@@ -7,8 +7,10 @@ inside verify) becomes batched lane-parallel kernels here, so the host
 ships only raw coordinate limbs + flag bits:
 
   - `g2_decompress_y`: y from x + wire sign bit (one Fp2 sqrt chain),
-  - `sswu_map_g2` + `iso3_map` + `clear_cofactor_g2`: the device mirror
-    of the host RFC 9380 pipeline (crypto/hash_to_curve.py:227-287);
+  - `sswu_map_g2` + `iso3_map` + `clear_cofactor_g2_sum`: the device
+    mirror of the host RFC 9380 pipeline (crypto/hash_to_curve.py:227-287),
+    the cofactor cleared by the psi endomorphism (RFC 9380 G.3) where
+    the host multiplies by h_eff;
     expand_message_xmd stays on the host (SHA-256, cheap, amortized by
     the per-slot SeenAttestationDatas cache) and ships u as plain limbs
     plus its sgn0 bit.
@@ -150,32 +152,9 @@ def iso3_map(pt):
     return (xn, yn), ok
 
 
-def clear_cofactor_g2(q_aff):
-    """[h_eff] Q, matching the host's plain scalar multiplication
-    byte-for-byte (crypto/hash_to_curve.py clear_cofactor_g2).
-
-    Generic square-and-multiply over the jacobian group via pow_static;
-    mixed adds assume no T == +-Q coincidence along the fixed h_eff
-    addition chain — hash outputs are (computationally) random full-group
-    points, so an intermediate multiple falling on +-Q has negligible
-    probability and cannot be steered by an adversary (preimage
-    resistance).  A psi-endomorphism fast path is a later optimization.
-    """
-    one = CV._one_plane_like(CV.FP2_OPS, q_aff[0])
-
-    def dbl(T):
-        return CV.jac_dbl(CV.FP2_OPS, T)
-
-    def add(T, _base):
-        return CV.jac_add_mixed(CV.FP2_OPS, T, q_aff)
-
-    T = (q_aff[0], q_aff[1], one)
-    return TW.pow_static(T, HC.H_EFF_G2, dbl, add, None)
-
-
 def hash_to_g2_values(u0, u1, u0_sgn0, u1_sgn0):
-    """Full map_to_curve for one message: two SSWU points, added on the
-    isogenous image, cofactor-cleared.  Returns jacobian planes + ok.
+    """Full map_to_curve for one message: two SSWU points, mapped to E2,
+    added and cofactor-cleared.  Returns jacobian planes + ok.
 
     The two maps run as one rolled 2-step loop, so the kernel holds the
     SSWU + isogeny code once (unrolled it was most of the kernel's cold
@@ -199,25 +178,74 @@ def hash_to_g2_values(u0, u1, u0_sgn0, u1_sgn0):
         0, 2, one_map, ((u0, u0), no, (u0, u0), no)
     )
     ok0, ok1 = ok0 != 0, ok1 != 0
-    # q0 + q1 (affine-affine via mixed jacobian add; q0 == +-q1 has
-    # negligible probability for hash outputs)
     one = CV._one_plane_like(CV.FP2_OPS, q0[0])
-    q0j = (q0[0], q0[1], one)
-    s = CV.jac_add_mixed(CV.FP2_OPS, q0j, q1)
-    cleared = clear_cofactor_g2_jac(s)
+    cleared = clear_cofactor_g2_sum((q0[0], q0[1], one), (q1[0], q1[1], one))
     return cleared, ok0 & ok1
 
 
-def clear_cofactor_g2_jac(q_jac):
-    """[h_eff] Q for a jacobian input (full adds)."""
+# Steps of clear_cofactor_g2_sum's loop.  |x| has 63 bits below its
+# leading one; each [|x|] chain starts from its base and doubles once
+# per bit.
+_X_BITS = CV._X_ABS.bit_length() - 1
+_MUL1 = 1  # 1..63: T = [|x|]P
+_T2 = _MUL1 + _X_BITS  # 64: t2 = -T + psi(P) = [x]P + psi(P)
+_MUL2 = _T2 + 1  # 65..127: T = [|x|]t2
+_TAIL = _MUL2 + _X_BITS  # 128: -T - t2; 129: -P; 130, 131: + psi^2(P)
+_STEPS = _TAIL + 4
 
-    def dbl(T):
-        return CV.jac_dbl(CV.FP2_OPS, T)
 
-    def add(T, base):
-        return CV.jac_add_mixed_or_full(CV.FP2_OPS, T, base)
+def clear_cofactor_g2_sum(q0, q1):
+    """[h_eff](q0 + q1) for jacobian q0, q1: the same point as the
+    host's plain h_eff multiplication (crypto/hash_to_curve.py
+    clear_cofactor_g2).
 
-    return TW.pow_static(q_jac, HC.H_EFF_G2, dbl, lambda T, _b: add(T, q_jac), None)
+    RFC 9380 Appendix G.3 (Budroni-Pintore): with x the negative BLS
+    parameter and psi the endomorphism of CV.g2_psi,
+    [h_eff]P = [x^2 - x - 1]P + [x - 1]psi(P) + psi^2(2P), evaluated as
+    t2 = [x]P + psi(P), then [x]t2 - t2 - P + psi^2(P) + psi^2(P), with
+    [x]T = -[|x|]T.  One rolled loop holds the whole chain, the sum
+    P = q0 + q1 first: each step doubles (inside a multiplication),
+    then adds its operand (every step outside one; at the set bits of
+    |x|, 5 of 63, inside), so the kernel holds one doubling, one
+    addition and one psi whatever the chain's length.
+
+    The additions skip the equal/infinity dispatch
+    (jac_add_mixed_or_full): hash outputs are (computationally) random
+    full-group points, so an accumulator falling on +-operand (or on O)
+    along this fixed chain has negligible probability and cannot be
+    steered by an adversary (preimage resistance).
+    """
+    fo = CV.FP2_OPS
+    bit_at = CV.static_bit_reader(CV._X_ABS)
+
+    def step(i, st):
+        T, P, U, S = st  # accumulator, P, psi register, t2
+        in1 = (i >= _MUL1) & (i < _T2)
+        in2 = (i >= _MUL2) & (i < _TAIL)
+        mul = in1 | in2
+        T = jax.lax.cond(mul, lambda t: CV.jac_dbl(fo, t), lambda t: t, T)
+        T = CV.pick((i == _T2) | (i == _TAIL), CV.jac_neg(fo, T), T)
+        op = CV.pick(i == 0, q1, U)  # U: psi(P) at _T2, psi^2(P) at the end
+        op = CV.pick(in1, P, op)
+        op = CV.pick(in2, S, op)
+        op = CV.pick(i == _TAIL, CV.jac_neg(fo, S), op)
+        op = CV.pick(i == _TAIL + 1, CV.jac_neg(fo, P), op)
+        bit = bit_at(jnp.where(in1, _T2 - 1 - i, _TAIL - 1 - i))
+        T = jax.lax.cond(
+            ~mul | (bit != 0),
+            lambda t: CV.jac_add_mixed_or_full(fo, t, op),
+            lambda t: t,
+            T,
+        )
+        P = CV.pick(i == 0, T, P)
+        U = CV.pick(i == 0, T, U)
+        U = jax.lax.cond(
+            (i == _MUL1) | (i == _MUL2), CV.g2_psi, lambda u: u, U
+        )
+        S = CV.pick(i == _T2, T, S)
+        return T, P, U, S
+
+    return jax.lax.fori_loop(0, _STEPS, step, (q0, q0, q0, q0))[0]
 
 
 # -- jitted wrappers (ingest entry points) ----------------------------------

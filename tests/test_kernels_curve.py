@@ -208,6 +208,40 @@ def test_g2_subgroup_check():
     assert got == [True] * 3 + [False] * 3
 
 
+@pytest.mark.parametrize("const, root", [("_CX_INT", 3), ("_CY_INT", 2)])
+def test_psi_constants_are_rfc9380s(const, root):
+    """psi's constants are RFC 9380 G.3's c1 = 1/(1+I)^((p-1)/3) and
+    c2 = 1/(1+I)^((p-1)/2)."""
+    assert getattr(CV, const) == GT.fp2_inv(GT.fp2_pow((1, 1), (P - 1) // root))
+
+
+def _host_psi(q):
+    return tuple(
+        GT.fp2_mul(GT.fp2_conj(c), k) for c, k in zip(q, (CV._CX_INT, CV._CY_INT))
+    )
+
+
+@pytest.mark.parametrize("msg", [b"cofactor 0", b"cofactor 1", b"cofactor 2"])
+def test_g3_cofactor_grouping_is_h_eff(msg):
+    """The G.3 grouping the hash kernel evaluates, t2 = [x]P + psi(P),
+    then [x]t2 - t2 - P + psi^2(P) + psi^2(P), equals the plain h_eff
+    multiplication for an SSWU sum P that is not in G2 (host integers;
+    the device loop is checked in tests/test_kernels_ingest.py)."""
+    fo, x = GC.FP2_OPS, GT.X_PARAM
+    u0, u1 = GH.hash_to_field_fp2(msg, 2, GH.DST_G2)
+    p = GC.affine_add(
+        fo,
+        GH.iso3_map(GH.map_to_curve_sswu_g2(u0)),
+        GH.iso3_map(GH.map_to_curve_sswu_g2(u1)),
+    )
+    assert not GC.g2_subgroup_check(p)
+    t2 = GC.affine_add(fo, GC.scalar_mul(fo, p, x), _host_psi(p))
+    psi2 = _host_psi(_host_psi(p))
+    terms = [GC.scalar_mul(fo, t2, x), GC.affine_neg(fo, t2), GC.affine_neg(fo, p)]
+    q = GC.multi_add(fo, terms + [psi2, psi2])
+    assert q == GH.clear_cofactor_g2(p)
+
+
 def test_sum_points_axis0_and_lanes():
     k, n = 5, 4
     pts = [rand_g1(n) for _ in range(k)]

@@ -6,6 +6,8 @@ path is the consensus-critical reference (reference ingest behavior:
 blst uncompress + hash inside packages/beacon-node/src/chain/bls/).
 """
 
+import random
+
 import numpy as np
 import pytest
 
@@ -218,3 +220,44 @@ def test_hash_to_g2_device_matches_host():
         x = GT.fp2_mul((X0[i], X1[i]), zi2)
         y = GT.fp2_mul((Y0[i], Y1[i]), GT.fp2_mul(zi2, zi))
         assert (x, y) == host_map[m], i
+
+
+def test_clear_cofactor_g2_sum_matches_host():
+    """The G.3 clearing loop on jacobian planes, fed SSWU sums that are
+    not in G2 before it, with Z != 1 (Z = 1 in lane 0, as the hash
+    kernel feeds it), equals the host's plain h_eff multiplication."""
+    rnd = random.Random(26)
+
+    def jac(q, z):
+        z2 = GT.fp2_sqr(z)
+        return (GT.fp2_mul(q[0], z2), GT.fp2_mul(q[1], GT.fp2_mul(z2, z)), z)
+
+    q0s, q1s, want = [], [], []
+    for i in range(4):
+        u0, u1 = HC.hash_to_field_fp2(b"cofactor %d" % i, 2, HC.DST_G2)
+        q0 = HC.iso3_map(HC.map_to_curve_sswu_g2(u0))
+        q1 = HC.iso3_map(HC.map_to_curve_sswu_g2(u1))
+        s = GC.affine_add(GC.FP2_OPS, q0, q1)
+        assert not GC.g2_subgroup_check(s)
+        want.append(HC.clear_cofactor_g2(s))
+        z0, z1 = [
+            (1, 0) if i == 0 else (rnd.randrange(1, P), rnd.randrange(P))
+            for _ in range(2)
+        ]
+        q0s.append(jac(q0, z0))
+        q1s.append(jac(q1, z1))
+
+    def planes(qs):
+        return tuple(
+            (enc_mont([q[c][0] for q in qs]), enc_mont([q[c][1] for q in qs]))
+            for c in range(3)
+        )
+
+    X, Y, Z = jax.jit(IN.clear_cofactor_g2_sum)(planes(q0s), planes(q1s))
+    (X0, X1), (Y0, Y1), (Z0, Z1) = [(dec(a), dec(b)) for a, b in (X, Y, Z)]
+    for i in range(4):
+        zi = GT.fp2_inv((Z0[i], Z1[i]))
+        zi2 = GT.fp2_sqr(zi)
+        x = GT.fp2_mul((X0[i], X1[i]), zi2)
+        y = GT.fp2_mul((Y0[i], Y1[i]), GT.fp2_mul(zi2, zi))
+        assert (x, y) == want[i], i
